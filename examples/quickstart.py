@@ -268,12 +268,13 @@ def main():
     # the scalar oracle (tests/core/test_halo.py pins this, and the traffic
     # guard proves collective flushes never touch the routed host
     # fetchers); exp18 holds collective >= 1.2x host flush throughput at
-    # 8 shards, batch 512. engine.halo_capacity bounds the padded
-    # per-shard-pair slot count (default 4096, rounded up to powers of
-    # two): a repair round too wide to fit falls back to the routed host
-    # path for that round only — counted in stats()['halo_fallbacks'],
-    # never visible in results. Raise it if fallbacks show up under heavy
-    # churn; lower it to cap exchange buffer memory on wide fan-outs.
+    # 8 shards, batch 512. engine.halo_capacity, when set, bounds the
+    # padded per-owner served-row count (rounded up to powers of two): a
+    # round too wide to fit falls back to the routed host path for that
+    # round only — counted in stats()['halo_fallbacks'], never visible in
+    # results. Unset (the default) no round falls back, since an owner
+    # serves at most its own rows; set it to cap exchange buffer memory on
+    # wide fan-outs.
     if sharded.num_shards > 1:
         sharded.stage_insert(int(np.setdiff1d(np.arange(g.n), sharded.objects)[0]))
         sharded.flush_updates()
@@ -283,11 +284,12 @@ def main():
     else:
         print("single shard - nothing crosses a boundary")
     # Cold boots recompile every serving program; a persistent compilation
-    # cache makes the SECOND process boot warm. serve.py --compile-cache DIR
-    # (or the REPRO_COMPILE_CACHE env var) configures it before anything
-    # compiles; programmatically it is one call, safe to leave on:
+    # cache makes the SECOND process boot warm. serve.py and knn_build.py
+    # turn it on before anything compiles, in JAX_COMPILATION_CACHE_DIR if
+    # set, else in the checkout's .jax_cache; programmatically it is one
+    # call, safe to leave on:
     #     from repro.analysis import sanitize
-    #     sanitize.enable_compile_cache("~/.cache/repro-xla")
+    #     sanitize.enable_compile_cache()
     # sanitize.count_compiles() splits real compiles from cache hits
     # (counter.uncached), which is how the cold-boot budget test holds a
     # warm-cache boot to the *warm* serving budgets.

@@ -19,7 +19,7 @@ Two rails:
   context managers the engines run their query/flush paths under in
   sanitizer mode (``REPRO_SANITIZE=1``), a compile counter checked against
   ``tools/compile_budgets.json``, a post-flush table invariant scanner, and
-  an aliasing sanitizer that replays each Pallas kernel on poisoned
+  a kernel sanitizer that replays each gathering Pallas kernel on poisoned
   pad/dummy slots against its ``kernels/ref.py`` oracle.
 
 ``sanitize`` is deliberately NOT imported here: the static rail must stay

@@ -31,7 +31,7 @@ from dataclasses import dataclass, field
 
 # Call-expression heads that turn their first function argument into a
 # device program. Matched on the attribute tail, so ``jax.jit``, ``jit``,
-# ``pjit``, ``pl.pallas_call`` and ``jax.experimental.shard_map.shard_map``
+# ``pjit``, ``pl.pallas_call`` and ``jax.shard_map``
 # all resolve the same way.
 _BOUNDARY_WRAPPERS = {"jit", "pjit", "shard_map", "pallas_call"}
 

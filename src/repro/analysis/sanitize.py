@@ -20,13 +20,13 @@ module checks the ones only an execution can see:
 * ``scan_tables()`` — post-flush invariant scan of the (n, k) tables:
   NaN / negative / unsorted distances, out-of-range ids, pad slots that
   carry finite distances.
-* ``check_kernel_aliasing()`` — replays the aliased Pallas kernels
+* ``check_kernel_poisoning()`` — replays the gathering Pallas kernels
   (``sweep_merge``, ``frontier_relax``) against their ``kernels/ref.py``
-  oracles with *poisoned* buffers: every slot the kernel must mask or
-  must not read through the donated operand (pad neighbor slots, the
-  dummy row, donated-table garbage) is filled with trap values first.
-  A kernel that reads through its aliased operand after the scatter, or
-  forgets a pad mask, diverges from the oracle here.
+  oracles with *poisoned* buffers: every slot the kernel must mask (pad
+  neighbor slots and their weights, the dummy row) is filled with trap
+  values first. A kernel that forgets a pad mask, picks the wrong row out
+  of a gathered tile, or reads a row the same step already rewrote,
+  diverges from the oracle here.
 
 Everything raises ``repro.core.errors.SanitizerError`` on violation.
 """
@@ -41,7 +41,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-import jax._src.monitoring as _monitoring
+from jax import monitoring
 
 from repro.core.errors import SanitizerError
 
@@ -112,31 +112,44 @@ class CompileCounter:
 @contextlib.contextmanager
 def count_compiles():
     counter = CompileCounter()
-    _monitoring.register_event_duration_secs_listener(counter._listen)
-    _monitoring.register_event_listener(counter._listen_event)
+    monitoring.register_event_duration_secs_listener(counter._listen)
+    monitoring.register_event_listener(counter._listen_event)
     try:
         yield counter
     finally:
-        _monitoring._unregister_event_duration_listener_by_callback(counter._listen)
-        _monitoring._unregister_event_listener_by_callback(counter._listen_event)
+        monitoring.unregister_event_duration_listener(counter._listen)
+        monitoring.unregister_event_listener(counter._listen_event)
 
 
-def enable_compile_cache(path: str | os.PathLike | None = None) -> Path | None:
-    """Turn on jax's persistent compilation cache at ``path``.
+_REPO = Path(__file__).resolve().parents[3]
 
-    ``path`` defaults to the ``REPRO_COMPILE_CACHE`` env var; returns the
-    cache directory (created if missing), or None when neither is set (the
-    call is then a no-op, so serve.py can wire it unconditionally). The
-    min-compile-time/min-entry-size floors are zeroed so even the CPU
-    backend's fast compiles persist — the point is cold-boot serving, and
-    a second boot should pay the *warm* budget, not the 28->2 win again.
+
+def compile_cache_dir() -> Path:
+    """Where the persistent compilation cache lives.
+
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (jax itself reads it at
+    import), else the fixed ``.jax_cache`` directory of this checkout. The
+    path is part of the cache key, so it is never built from a temporary
+    name, a process id or the time.
     """
-    path = path or os.environ.get("REPRO_COMPILE_CACHE") or None
-    if not path:
-        return None
-    path = Path(path)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return Path(env) if env else _REPO / ".jax_cache"
+
+
+def enable_compile_cache() -> Path:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    The one place the cache is set up: ``serve.py``, ``knn_build.py`` and
+    ``chip_smoke.py`` all call this before their first compile. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, jax already points there and no
+    other directory is set. The min-compile-time/min-entry-size floors are
+    zeroed so even the CPU backend's fast compiles persist — the point is
+    cold-boot serving, and a second boot should pay the *warm* budget.
+    """
+    path = compile_cache_dir()
     path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
@@ -146,7 +159,7 @@ def budgets_path() -> Path:
     env = os.environ.get("REPRO_COMPILE_BUDGETS")
     if env:
         return Path(env)
-    return Path(__file__).resolve().parents[3] / "tools" / "compile_budgets.json"
+    return _REPO / "tools" / "compile_budgets.json"
 
 
 def load_budgets() -> dict:
@@ -278,18 +291,17 @@ def scan_tables(ids, dists, n: int, *, context: str = "") -> None:
 
 
 # ---------------------------------------------------------------------------
-# aliasing sanitizer: poisoned kernels vs host oracles
+# kernel sanitizer: poisoned kernels vs host oracles
 # ---------------------------------------------------------------------------
 
 
-def check_kernel_aliasing(*, k: int = 4, seed: int = 0, interpret: bool = True) -> None:
-    """Replay the aliased Pallas kernels on poisoned inputs vs ref oracles.
+def check_kernel_poisoning(*, k: int = 4, seed: int = 0, interpret: bool = True) -> None:
+    """Replay the gathering Pallas kernels on poisoned inputs vs ref oracles.
 
     Poison pattern: pad neighbor slots carry huge finite garbage behind
-    their -1 ids, the dummy row holds NaN-free trap values, and the
-    donated (aliased) table operand is a *separate copy* whose trap slots
-    differ from the read operand's — any read through the wrong operand or
-    an unmasked pad slot shows up as an exact-equality miss vs the oracle.
+    their -1 ids and the dummy row holds NaN-free trap values — an unmasked
+    pad slot or a non-Jacobi read shows up as an exact-equality miss vs the
+    oracle.
     """
     from repro.kernels import ref
     from repro.kernels.frontier_relax import frontier_relax_pallas
@@ -324,7 +336,7 @@ def check_kernel_aliasing(*, k: int = 4, seed: int = 0, interpret: bool = True) 
     got = sweep_merge_pallas(
         jnp.asarray(nbr), jnp.asarray(verts), jnp.asarray(w),
         jnp.asarray(ex_ids), jnp.asarray(ex_d),
-        jnp.asarray(vk_ids), jnp.asarray(vk_d),  # donated copy
+        jnp.asarray(vk_ids), jnp.asarray(vk_d),
         k=k, interpret=interpret,
     )
     for name, g, wnt in (("ids", got[0], want[0]), ("dists", got[1], want[1])):
@@ -333,10 +345,10 @@ def check_kernel_aliasing(*, k: int = 4, seed: int = 0, interpret: bool = True) 
             bad = int((g != wnt).sum())
             raise SanitizerError(
                 f"sweep_merge diverges from ref oracle on poisoned buffers "
-                f"({name}: {bad} cells) — aliased-operand read or pad-mask bug"
+                f"({name}: {bad} cells) — non-Jacobi read or pad-mask bug"
             )
 
-    # --- frontier_relax: aliased (n+1, B) scatter, Jacobi read discipline --
+    # --- frontier_relax: (n+1, B) scatter, Jacobi read discipline ----------
     r, tt, b = 5, 3, 4
     nbr2 = rng.integers(0, n, (r, tt), dtype=np.int32)
     nbr2[1, -1] = -1
@@ -363,5 +375,5 @@ def check_kernel_aliasing(*, k: int = 4, seed: int = 0, interpret: bool = True) 
         bad = int((got2 != np.asarray(want2, np.float32)).sum())
         raise SanitizerError(
             f"frontier_relax diverges from ref oracle on poisoned buffers "
-            f"({bad} cells) — the Jacobi aliased-read discipline is broken"
+            f"({bad} cells) — the Jacobi read discipline is broken"
         )

@@ -52,9 +52,10 @@ jitted form:
   the batched path is property-tested ``indices_equivalent`` against it.
 
   The repair rounds use the merge's XLA form (functional gather-then-scatter)
-  rather than the in-place Pallas kernel: repaired rows read each other, so
-  the level-schedule disjointness the fused kernel's aliasing relies on does
-  not hold here.
+  on every engine. Repaired rows read each other, so a round must be pure
+  Jacobi: every read sees the pre-round tables. The XLA form is; so is the
+  Pallas ``sweep_merge``, which emits its merged rows for an XLA scatter and
+  never writes the tables it reads.
 
 * ``save`` / ``load`` — one ``.npz`` artifact (ids, dists, k, object set,
   format version + shard meta) shared by ``knn_build.py --out`` and the
@@ -1468,8 +1469,7 @@ def _repair_round(nbr_tab, w_tab, rows, vk_ids, vk_d):
     """One Jacobi repair round: every row in ``rows`` re-merges its own
     entries (extras tables = the live tables themselves) with its bridge
     neighbors' rows; returns the per-row changed mask the caller uses to
-    narrow the next round's frontier. use_pallas=False in the merge is
-    required, not a tuning choice — see the module docstring.
+    narrow the next round's frontier (Jacobi: see the module docstring).
     """
     k = vk_ids.shape[1]
     nbr = nbr_tab[rows]

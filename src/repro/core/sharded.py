@@ -64,8 +64,8 @@ Execution model
   rows move as capacity-padded ``all_gather`` multicasts inside the
   shard_map programs and the receiver-set expansion runs on device as a
   psum'd presence mask, so per round only the integer index plans go up
-  and one changed-row mask comes back; a plan that overflows
-  ``halo_capacity`` falls back for that round. ``halo = "host"`` replays
+  and one changed-row mask comes back; a plan that overflows a set
+  ``halo_capacity`` (none by default) falls back for that round. ``halo = "host"`` replays
   the routed-gather baseline (host-fetched unique rows, numpy set
   algebra) — kept as the exp18 measurable baseline and the collective
   path's bit-identity twin.
@@ -116,7 +116,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import (
     Mesh,
     NamedSharding,
@@ -980,9 +980,12 @@ class ShardedQueryEngine(EngineCore):
         # row layout (built lazily, dropped on every layout change so halo
         # row maps can never outlive their boundaries), plus the per-round
         # all_gather capacity cap — a round whose padded per-owner served-
-        # row count exceeds it falls back to the routed host halo
+        # row count exceeds it falls back to the routed host halo. None (the
+        # default) never falls back: an owner serves at most the rows it
+        # holds, so the receive buffer is at most S x block rows, the size
+        # of the frontier's own dist matrix
         self._nbr_glob_g: jax.Array | None = None
-        self.halo_capacity = 4096
+        self.halo_capacity: int | None = None
         self._halo_stats = {
             "halo_rounds_collective": 0,
             "halo_fallbacks": 0,
@@ -1003,6 +1006,7 @@ class ShardedQueryEngine(EngineCore):
             "replica_queries": 0,
             "replica_batches": 0,
             "replica_errors": 0,
+            "balanced_batches": 0,  # unreplicated two-phase gathers
         }
 
     # ------------------------------------------------------------------
@@ -1485,10 +1489,12 @@ class ShardedQueryEngine(EngineCore):
         ``np.asarray`` on a multi-MB tile allocates a fresh mmap'd buffer
         every call, and the page-fault churn is bimodal across processes —
         enough to flap the exp16 floor. Copying through a reused staging
-        buffer (zero-copy dlpack view of each shard, two rotating buffers
+        buffer (one copy of each shard, two rotating buffers
         per shape so the bytes a just-dispatched ``device_put`` reads are
         never overwritten by the next batch) keeps the copy on the warm
-        memcpy path."""
+        memcpy path. Each shard is read with ``np.asarray``, which works on
+        any backend's buffers (numpy's DLPack import takes CPU buffers
+        only)."""
         key = (x.shape, str(x.dtype))
         pair = self._cons_bufs.get(key)
         if pair is None:
@@ -1497,8 +1503,8 @@ class ShardedQueryEngine(EngineCore):
             )
         buf = pair[pair[2]]
         pair[2] ^= 1
-        for j, sh in enumerate(x.addressable_shards):
-            np.copyto(buf[j], np.from_dlpack(sh.data)[0])
+        for sh in x.addressable_shards:
+            np.copyto(buf[sh.index], np.asarray(sh.data))
         return buf
 
     def _gather_replicated(
@@ -1581,6 +1587,7 @@ class ShardedQueryEngine(EngineCore):
             # path.
             lead = SingleDeviceSharding(self.mesh.devices.flat[0])
             gi, gd = fns["gather_tile"](ids_g, d_g, self._put_shard(qglob))
+            self._rstats["balanced_batches"] += 1
             return fns["gather_epi"](
                 jax.device_put(self._consolidate(gi), lead),
                 jax.device_put(self._consolidate(gd), lead),
@@ -1766,7 +1773,7 @@ class ShardedQueryEngine(EngineCore):
         own_u = lay.owner(uniq)
         order_u, src_sorted, within, umax = self._group_by_owner(own_u)
         umax = _pow2_pad(umax, lo=16)
-        if umax > self.halo_capacity:
+        if self.halo_capacity is not None and umax > self.halo_capacity:
             return None
         serve = np.full((s, umax), -1, np.int32)
         serve[src_sorted, within] = lay.padded_rows(uniq[order_u], src_sorted)

@@ -1,9 +1,11 @@
-"""Jitted public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels and their XLA forms.
 
-Each wrapper pads inputs to the kernel's tiling constraints, picks
-interpret-mode automatically off-TPU (the container target is TPU v5e; CPU
-runs validate the kernel bodies), and falls back to the jnp reference when a
-shape is too small to be worth tiling.
+``use_pallas`` picks the Pallas kernel or the plain XLA form of the same
+math. On the Pallas path a wrapper pads inputs to the kernel's tiling
+constraints and, unless ``interpret`` is given, runs the kernel compiled on a
+TPU and in interpret mode on any other backend (CPU runs validate the kernel
+bodies; tests/kernels/test_tpu_compile.py checks that they compile for the
+chip).
 """
 from __future__ import annotations
 
@@ -17,8 +19,8 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.frontier_relax import frontier_relax_pallas
 from repro.kernels.minplus import minplus_matmul_pallas
 from repro.kernels.retrieval_topk import retrieval_topk_pallas
-from repro.kernels.sweep_merge import kround_merge, sweep_merge_pallas
-from repro.kernels.topk_merge import topk_merge_pallas
+from repro.kernels.sweep_merge import sweep_merge_pallas
+from repro.kernels.topk_merge import kround_merge, topk_merge_pallas
 
 
 def _on_tpu() -> bool:
@@ -45,9 +47,17 @@ def topk_merge(
     use_pallas: bool = True,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Top-k distinct-(id) merge. cand_ids: (B, C) int32 (-1 invalid)."""
+    """Top-k distinct-(id) merge. cand_ids: (B, C) int32 (-1 invalid).
+
+    The XLA form is the kernel's own ``kround_merge`` loop, not the
+    sort-based ``ref.topk_merge_ref`` oracle: its two vmapped lexsorts take
+    seconds to compile for a TPU (this loop well under one), once per
+    candidate shape, and a flush compiles one shape per round width.
+    """
     if not use_pallas:
-        return ref.topk_merge_ref(cand_ids, cand_d, k)
+        d = jnp.where(cand_ids < 0, jnp.inf, cand_d.astype(jnp.float32))
+        m_ids, m_d = kround_merge([(cand_ids, d)], k)
+        return m_ids, m_d.astype(cand_d.dtype)
     b = cand_ids.shape[0]
     ids = _pad_to(_pad_to(cand_ids, 1, 128, -1), 0, block_b, -1)
     d = _pad_to(_pad_to(cand_d, 1, 128, jnp.inf), 0, block_b, jnp.inf)
@@ -78,8 +88,8 @@ def sweep_merge(
     does no padding or jit of its own: the caller guarantees the layout
     invariants (padded slots -1/+inf, dummy row n).
 
-    The XLA fallback materialises the (CHUNK, T*k+E) candidate tensor and runs
-    the same k-round merge; the Pallas path never materialises it (see
+    The XLA form materialises the (CHUNK, T*k) gathered candidates and runs
+    the same k-round merge; the Pallas path never materialises them (see
     sweep_merge.py).
     """
     if not use_pallas:
@@ -88,13 +98,14 @@ def sweep_merge(
         valid = nbr >= 0
         nbr_c = jnp.where(valid, nbr, n1 - 1)
         g_ids = jnp.where(valid[..., None], vk_ids[nbr_c], -1)
-        g_d = w[..., None] + vk_d[nbr_c]
-        cand_ids = jnp.concatenate([g_ids.reshape(chunk, t * k), ex_ids[verts]], axis=1)
-        cand_d = jnp.concatenate(
-            [g_d.reshape(chunk, t * k), ex_d[verts]], axis=1
-        ).astype(jnp.float32)
-        cand_d = jnp.where(cand_ids < 0, jnp.inf, cand_d)
-        m_ids, m_d = kround_merge(cand_ids, cand_d, k)
+        g_ids = g_ids.reshape(chunk, t * k)
+        g_d = (w[..., None] + vk_d[nbr_c]).reshape(chunk, t * k)
+        e_ids = ex_ids[verts]
+        m_ids, m_d = kround_merge(
+            [(g_ids, jnp.where(g_ids < 0, jnp.inf, g_d)),
+             (e_ids, jnp.where(e_ids < 0, jnp.inf, ex_d[verts].astype(jnp.float32)))],
+            k,
+        )
         return vk_ids.at[verts].set(m_ids), vk_d.at[verts].set(m_d)
     itp = (not _on_tpu()) if interpret is None else interpret
     return sweep_merge_pallas(
@@ -422,18 +433,22 @@ def halo_fold_min(
     form and the host-routed fold in ``_frontier_part``. Miss slots
     (``slot == M``) clamp their gather to the last row and are masked to
     +inf, so no sentinel row is ever materialized; min is fold-order-
-    insensitive, so the distance trajectories stay bit-identical.
+    insensitive, so the distance trajectories stay bit-identical. The fold
+    starts from column 0 rather than an all-+inf constant, so inside
+    ``shard_map`` the loop carry varies over the shard axis like the body's
+    result (``slot``/``w`` are per-shard operands).
     """
-    t = slot.shape[1]
     m = recv.shape[0]
 
-    def body(j, cand):
+    def column(j):
         sl = slot[:, j]
         row = w[:, j, None] + recv[jnp.minimum(sl, m - 1)]
-        return jnp.minimum(cand, jnp.where((sl < m)[:, None], row, jnp.inf))
+        return jnp.where((sl < m)[:, None], row, jnp.inf)
 
-    init = jnp.full((slot.shape[0], recv.shape[1]), jnp.inf, jnp.float32)
-    return jax.lax.fori_loop(0, t, body, init)
+    def body(j, cand):
+        return jnp.minimum(cand, column(j))
+
+    return jax.lax.fori_loop(1, slot.shape[1], body, column(0))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))

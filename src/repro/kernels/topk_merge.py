@@ -26,32 +26,50 @@ from jax.experimental import pallas as pl
 _INT_MAX = jnp.iinfo(jnp.int32).max
 
 
+def kround_merge(groups, k: int):
+    """k rounds of dedup min-selection, row by row, over candidate groups.
+
+    ``groups`` is a sequence of ``(ids, d)`` pairs, each (b, c_g) with its
+    own width; row r's candidate set is the union of row r of every group,
+    so callers never concatenate along lanes. ``d`` must already be +inf
+    wherever ``ids < 0``. Semantics match ref.topk_merge_ref: the k
+    smallest-distance distinct ids per row, distance ties broken by the
+    smaller id, exhausted slots -> (-1, inf). Branch-free, and slot i is
+    written through an iota mask, so the same code runs in XLA and inside
+    the Pallas TPU kernels (which cannot lower ``dynamic_update_slice``).
+    """
+    b = groups[0][0].shape[0]
+    ids = tuple(g[0] for g in groups)
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
+
+    def rowmin(xs):
+        return functools.reduce(
+            jnp.minimum, [jnp.min(x, axis=1, keepdims=True) for x in xs]
+        )
+
+    def pick(i, out_ids, out_d, ds):
+        dmin = rowmin(ds)
+        # tie-break: smallest id among distance ties
+        idmin = rowmin(jnp.where(d == dmin, g, _INT_MAX) for g, d in zip(ids, ds))
+        ok = dmin < jnp.inf
+        here = col == i
+        out_ids = jnp.where(here, jnp.where(ok, idmin, -1), out_ids)
+        out_d = jnp.where(here, jnp.where(ok, dmin, jnp.inf), out_d)
+        # drop every candidate carrying the selected id -> dedup for free
+        ds = tuple(jnp.where(g == idmin, jnp.inf, d) for g, d in zip(ids, ds))
+        return out_ids, out_d, ds
+
+    # slot 0 is peeled so the loop carry derives from the candidates: inside
+    # shard_map it then varies over the mesh axis like the body's result
+    first = pick(0, -1, jnp.inf, tuple(g[1] for g in groups))
+    out_ids, out_d, _ = jax.lax.fori_loop(1, k, lambda i, c: pick(i, *c), first)
+    return out_ids, out_d
+
+
 def _topk_merge_kernel(ids_ref, d_ref, oid_ref, od_ref, *, k: int):
     ids = ids_ref[...]
-    d = d_ref[...].astype(jnp.float32)
-    d = jnp.where(ids < 0, jnp.inf, d)  # padding / invalid candidates
-
-    def body(i, carry):
-        out_ids, out_d, cd = carry
-        dmin = jnp.min(cd, axis=1)
-        # tie-break: smallest id among distance ties
-        idmin = jnp.min(jnp.where(cd == dmin[:, None], ids, _INT_MAX), axis=1)
-        valid = jnp.isfinite(dmin)
-        sel_id = jnp.where(valid, idmin, -1)
-        sel_d = jnp.where(valid, dmin, jnp.inf)
-        out_ids = jax.lax.dynamic_update_slice(out_ids, sel_id[:, None], (0, i))
-        out_d = jax.lax.dynamic_update_slice(out_d, sel_d[:, None], (0, i))
-        # mask every candidate carrying the selected id -> dedup
-        cd = jnp.where(ids == idmin[:, None], jnp.inf, cd)
-        return out_ids, out_d, cd
-
-    b = ids.shape[0]
-    init = (
-        jnp.full((b, k), -1, jnp.int32),
-        jnp.full((b, k), jnp.inf, jnp.float32),
-        d,
-    )
-    out_ids, out_d, _ = jax.lax.fori_loop(0, k, body, init)
+    d = jnp.where(ids < 0, jnp.inf, d_ref[...].astype(jnp.float32))
+    out_ids, out_d = kround_merge([(ids, d)], k)
     oid_ref[...] = out_ids
     od_ref[...] = out_d.astype(od_ref.dtype)
 

@@ -18,6 +18,7 @@ import json
 import time
 
 from repro import knn
+from repro.analysis import sanitize
 from repro.core.construct_jax import build_knn_tables_jax, prepare_sweep
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--verify", action="store_true", help="check vs host reference")
     ap.add_argument("--out", default=None, help="write a QueryEngine.save npz")
     args = ap.parse_args()
+    sanitize.enable_compile_cache()  # before anything compiles
 
     t0 = time.perf_counter()
     g = knn.road_network(args.grid, args.grid, seed=args.seed)

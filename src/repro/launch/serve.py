@@ -72,9 +72,10 @@ mid-run (``--hot-flip-round``) triggers a second re-split after the
 cooldown. The JSON stats report the active plan under ``"partition"`` and
 the re-split history under ``"repartition_rounds"``.
 
-``--compile-cache DIR`` (or ``REPRO_COMPILE_CACHE``) persists compiled XLA
-executables across processes, so a cold boot over a warm cache dir skips
-the expensive compiles.
+Compiled XLA executables persist across processes in the directory
+``JAX_COMPILATION_CACHE_DIR`` names, or else in the checkout's fixed
+``.jax_cache`` (``sanitize.enable_compile_cache``), so a cold boot over a
+warm cache skips the expensive compiles.
 """
 from __future__ import annotations
 
@@ -550,11 +551,6 @@ def main():
                          "history the drift detector slides over")
     ap.add_argument("--rebalance-cooldown", type=int, default=4,
                     help="knn ranges=auto: minimum rounds between re-splits")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(REPRO_COMPILE_CACHE env var is the fallback); a "
-                         "second process over the same dir skips cold "
-                         "compiles")
     ap.add_argument("--use-pallas", action="store_true")
     args = ap.parse_args()
 
@@ -562,7 +558,7 @@ def main():
 
     # must run before anything compiles: the cache dir only helps programs
     # compiled after it is configured
-    sanitize.enable_compile_cache(args.compile_cache)
+    sanitize.enable_compile_cache()
 
     arch = get_arch(args.arch)
     if arch.family == "lm":

@@ -1,4 +1,4 @@
-"""Runtime rail: transfer guard, compile budgets, table scans, aliasing.
+"""Runtime rail: transfer guard, compile budgets, table scans, kernel poisoning.
 
 The integration tests at the bottom pin the serving paths to the
 checked-in ``tools/compile_budgets.json``: the warm counts must EQUAL the
@@ -135,12 +135,12 @@ def test_scan_tables_rejects_corruption(mutate, msg):
 
 
 # ---------------------------------------------------------------------------
-# aliasing sanitizer (poisoned kernels vs oracles)
+# kernel sanitizer (poisoned kernels vs oracles)
 # ---------------------------------------------------------------------------
 
 
 def test_kernel_aliasing_oracle_parity():
-    sanitize.check_kernel_aliasing(interpret=True)
+    sanitize.check_kernel_poisoning(interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -199,15 +199,13 @@ def test_flush_updates_compile_budget(small_engine):
 
 _COLD_BOOT = """
 import json
-import os
 
-os.environ.setdefault("REPRO_COMPILE_CACHE", {cache!r})
 import numpy as np
 from repro.analysis import sanitize
 
-# {how}: the dir flag and the env fallback are the same surface serve.py
-# exposes via --compile-cache / REPRO_COMPILE_CACHE
-assert sanitize.enable_compile_cache({arg}) is not None
+# the one cache surface serve.py, knn_build.py and chip_smoke.py share:
+# JAX_COMPILATION_CACHE_DIR, set by the parent test
+assert str(sanitize.enable_compile_cache()) == {cache!r}
 
 from repro import knn
 from repro.core.reference import knn_index_cons_plus
@@ -229,26 +227,21 @@ print(json.dumps({{"count": c.count, "uncached": c.uncached}}))
 """
 
 
-def test_compile_cache_cold_boot_budget(tmp_path, devices_subprocess):
+def test_compile_cache_cold_boot_budget(tmp_path, devices_subprocess, monkeypatch):
     """A second process booting over a warm persistent cache dir must do
     no real compiles: its uncached count (backend compiles minus cache
     hits) must fit the *warm* serving budgets — a cold boot that recompiles
     is exactly the regression the cache exists to prevent."""
     cache = str(tmp_path / "xla-cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     first = json.loads(
-        devices_subprocess(
-            _COLD_BOOT.format(cache=cache, arg=repr(cache), how="dir flag"),
-            n_devices=1,
-        )
+        devices_subprocess(_COLD_BOOT.format(cache=cache), n_devices=1)
     )
     # the cold process really compiled, and every program landed in the dir
     assert first["uncached"] > 0
     assert any(os.scandir(cache))
     second = json.loads(
-        devices_subprocess(
-            _COLD_BOOT.format(cache=cache, arg=None, how="env fallback"),
-            n_devices=1,
-        )
+        devices_subprocess(_COLD_BOOT.format(cache=cache), n_devices=1)
     )
     budgets = json.loads(
         (Path(__file__).parents[2] / "tools" / "compile_budgets.json").read_text()
@@ -263,5 +256,11 @@ def test_compile_cache_cold_boot_budget(tmp_path, devices_subprocess):
 
 
 def test_enable_compile_cache_noop_without_path(monkeypatch):
-    monkeypatch.delenv("REPRO_COMPILE_CACHE", raising=False)
-    assert sanitize.enable_compile_cache(None) is None
+    """Without ``JAX_COMPILATION_CACHE_DIR`` the cache is no longer off: it
+    lands at the fixed in-checkout ``.jax_cache`` (the same path on every
+    run, so a second run hits); with the variable set, that directory wins."""
+    repo = Path(__file__).resolve().parents[2]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert sanitize.compile_cache_dir() == repo / ".jax_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert sanitize.compile_cache_dir() == Path("/some/dir")

@@ -171,6 +171,31 @@ def test_collective_flush_never_calls_host_fetchers():
     assert coll.stats()["halo_fallbacks"] == 0
 
 
+_WIDE_PLAN = """
+import numpy as np
+from repro import knn
+from repro.graph.generators import pick_objects, road_network
+
+g = road_network(66, 66, seed=0)
+bn = knn.build_bngraph(g)
+eng = knn.build_sharded_engine(bn, pick_objects(g.n, 0.02, seed=0), 6, shards=2)
+eng.repartition(np.array([0, 64]))  # shard 1 owns 4292 rows
+eng._nbr_tables()
+part = np.arange(g.n, dtype=np.int32)
+t = eng._t_bucket(part)
+plan = eng._halo_plan(part, eng._nbr_ids[part, :t], eng._nbr_w[part, :t])
+print("UMAX", plan[0].shape[1])
+"""
+
+
+def test_halo_plan_fits_any_round_by_default(devices_subprocess):
+    """With no ``halo_capacity`` set, a round whose receivers span a whole
+    4292-row shard still gets a collective plan (a fixed 4096-row cap used
+    to send such rounds to the host halo at realistic grid sizes)."""
+    out = devices_subprocess(_WIDE_PLAN, n_devices=2)
+    assert int(out.split("UMAX")[1]) == 8192
+
+
 @pytest.mark.skipif(DEVICES < 2, reason="collective halo needs >= 2 devices")
 def test_halo_overflow_falls_back_to_routed_path():
     """A capacity the halo cannot fit under must degrade to the routed host

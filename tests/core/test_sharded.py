@@ -287,3 +287,41 @@ def test_shard_tables_layout():
     covered = {(v // r) * (r + 1) + v % r for v in range(n)}
     for row in set(range(s * (r + 1))) - covered:
         assert (host[row] == -1).all()
+
+
+_CONSOLIDATE = """
+import numpy as np
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro import knn
+from repro.graph.generators import pick_objects, road_network
+
+g = road_network(12, 12, seed=0)
+objects = pick_objects(g.n, 0.15, seed=0)
+bn = knn.build_bngraph(g)
+plain = knn.QueryEngine.build(bn, objects, 6)
+sharded = knn.build_sharded_engine(bn, objects, 6, shards=4)
+
+def no_dlpack(*a, **kw):
+    raise AssertionError("np.from_dlpack takes CPU buffers only")
+np.from_dlpack = no_dlpack
+
+x = np.arange(4 * 8 * 6, dtype=np.float32).reshape(4, 8, 6)
+tile = jax.device_put(x, NamedSharding(sharded.mesh, P("shard", None, None)))
+assert len({sh.device for sh in tile.addressable_shards}) == 4
+for _ in range(3):  # both pooled staging buffers, then the first again
+    assert np.array_equal(sharded._consolidate(tile), x)
+
+us = np.random.default_rng(1).integers(0, g.n, size=4096).astype(np.int32)
+for a, b in zip(plain.query_batch(us), sharded.query_batch(us)):
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+assert sharded.stats()["balanced_batches"] == 1
+print("CONSOLIDATE_OK")
+"""
+
+
+def test_consolidate_reads_shards_without_dlpack(devices_subprocess):
+    """The balanced gather's host consolidation reads each shard without
+    ``np.from_dlpack`` (CPU buffers only, so it fails on a TPU) and gives the
+    same tile and the same answers as the scalar engine."""
+    assert "CONSOLIDATE_OK" in devices_subprocess(_CONSOLIDATE, n_devices=4)

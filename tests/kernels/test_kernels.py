@@ -13,12 +13,13 @@ def _rng(seed=0):
 @pytest.mark.parametrize("b", [1, 7, 128, 300])
 @pytest.mark.parametrize("c,k", [(16, 3), (130, 10), (257, 20)])
 @pytest.mark.parametrize("dtype", [np.float32, np.float16])
-def test_topk_merge_sweep(b, c, k, dtype):
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_topk_merge_sweep(b, c, k, dtype, use_pallas):
     rng = _rng(b * 1000 + c)
     ids = rng.integers(0, max(4, c // 3), size=(b, c)).astype(np.int32)
     ids[rng.random((b, c)) < 0.15] = -1
     d = np.round(rng.uniform(0, 64, size=(b, c)), 1).astype(dtype)
-    got_i, got_d = ops.topk_merge(jnp.asarray(ids), jnp.asarray(d), k)
+    got_i, got_d = ops.topk_merge(jnp.asarray(ids), jnp.asarray(d), k, use_pallas=use_pallas)
     want_i, want_d = ref.topk_merge_ref(jnp.asarray(ids), jnp.asarray(d), k)
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_allclose(
