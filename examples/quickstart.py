@@ -143,12 +143,16 @@ def main():
           f"{flush['rows_merged']} rows merged in "
           f"{flush['frontier_rounds']} frontier rounds")
     st = batch_engine.stats()
-    # per-phase flush timings (cumulative): where a flush actually spends
-    # its time — frontier search vs fused purge+merge vs delete repair
-    print("per-phase flush seconds: "
-          f"frontier={st['t_frontier_s']:.4f} "
-          f"purge_merge={st['t_purge_merge_s']:.4f} "
-          f"repair={st['t_repair_s']:.4f}")
+    # host span totals (cumulative, repro.core.spans): where a flush's host
+    # time goes — frontier rounds, purge-merge enqueue, repair rounds, and
+    # the readbacks that wait on the device; the same knn:* spans land in a
+    # jax.profiler trace when one is open
+    print("flush span seconds (calls): " + ", ".join(
+        f"{name}={tot['s']:.4f} ({tot['n']})"
+        for name, tot in st["spans"].items() if name.startswith("knn:flush")))
+    print(f"flush transfers: {st['flush_readbacks']} readbacks "
+          f"({st['flush_readback_bytes']} B), {st['flush_uploads']} uploads "
+          f"({st['flush_upload_bytes']} B)")
 
     print("\n== 10. durability & epochs (crash-safe serving) ==")
     # Every flush publishes a new immutable epoch: queries resolve their

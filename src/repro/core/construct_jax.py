@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core.bngraph import BNGraph
 from repro.core.index import KNNIndex
+from repro.core.spans import span
 from repro.kernels import ops
 
 _INF = np.float32(np.inf)
@@ -279,13 +280,14 @@ def object_extras(n: int, objects: np.ndarray, k: int) -> tuple[jax.Array, jax.A
     Padded to E = k columns so both sweeps share extra shapes (and therefore
     compiled programs) wherever their bucket signatures coincide.
     """
-    is_obj = np.zeros(n, dtype=bool)
-    is_obj[objects] = True
-    ex_ids = np.full((n + 1, k), -1, np.int32)
-    ex_ids[:n, 0] = np.where(is_obj, np.arange(n, dtype=np.int32), -1)
-    ex_d = np.full((n + 1, k), _INF, np.float32)
-    ex_d[:n, 0] = np.where(is_obj, np.float32(0), _INF)
-    return jax.device_put(ex_ids), jax.device_put(ex_d)
+    with span("build.extras"):
+        is_obj = np.zeros(n, dtype=bool)
+        is_obj[objects] = True
+        ex_ids = np.full((n + 1, k), -1, np.int32)
+        ex_ids[:n, 0] = np.where(is_obj, np.arange(n, dtype=np.int32), -1)
+        ex_d = np.full((n + 1, k), _INF, np.float32)
+        ex_d[:n, 0] = np.where(is_obj, np.float32(0), _INF)
+        return jax.device_put(ex_ids), jax.device_put(ex_d)
 
 
 def build_knn_tables_jax(
@@ -314,18 +316,21 @@ def build_knn_tables_jax(
     one dummy gather row per shard — still without reading the tables back
     to the host (see ``repro.core.sharded.shard_tables``).
     """
-    ex_ids, ex_d = object_extras(bn.n, objects, k)
-    plan_up, plan_down = plans or (prepare_sweep(bn, "up"), prepare_sweep(bn, "down"))
+    with span("build"):
+        ex_ids, ex_d = object_extras(bn.n, objects, k)
+        plan_up, plan_down = plans or (prepare_sweep(bn, "up"), prepare_sweep(bn, "down"))
 
-    # ---- bottom-up: V_k^< (Lemma 5.12) ----
-    vkl_ids, vkl_d = run_sweep(plan_up, ex_ids, ex_d, k, use_pallas=use_pallas)
-    # ---- top-down: V_k (Lemma 5.21), extras = own V_k^< rows, still on device ----
-    vk_ids, vk_d = run_sweep(plan_down, vkl_ids, vkl_d, k, use_pallas=use_pallas)
-    if mesh is None:
-        return vk_ids, vk_d
-    from repro.core.sharded import shard_tables
+        # ---- bottom-up: V_k^< (Lemma 5.12) ----
+        with span("build.sweep", direction="up"):
+            vkl_ids, vkl_d = run_sweep(plan_up, ex_ids, ex_d, k, use_pallas=use_pallas)
+        # ---- top-down: V_k (Lemma 5.21), extras = own V_k^< rows, still on device ----
+        with span("build.sweep", direction="down"):
+            vk_ids, vk_d = run_sweep(plan_down, vkl_ids, vkl_d, k, use_pallas=use_pallas)
+        if mesh is None:
+            return vk_ids, vk_d
+        from repro.core.sharded import shard_tables
 
-    return shard_tables(vk_ids, vk_d, bn.n, mesh, starts=shard_starts)
+        return shard_tables(vk_ids, vk_d, bn.n, mesh, starts=shard_starts)
 
 
 def build_knn_index_jax(

@@ -102,13 +102,21 @@ distance tiles come back. The (n,) k-th-distance column — the checkIns
 pruning bound — never leaves the device: the frontier rounds read it
 straight off the live distance table, so per-flush readback is proportional
 to the affected set, not to n. Queries move only the query ids up and the
-(B, k) result tiles back.
+(B, k) result tiles back. Every readback of a flush goes through
+``EngineCore._readback`` and every upload through ``_upload``, which count
+them (``stats()["flush_readbacks"]`` ..., per flush in ``epoch_stats(e)``);
+each readback is a ``knn:flush.readback`` span, the host waiting on the
+device.
+
+Host spans: queries, flush phases and rounds, and the build run in named
+``knn:*`` spans (``repro.core.spans``), written into a ``jax.profiler``
+trace when one is open and totalled in ``stats()["spans"]``.
 
 Everything above that is *layout-independent* — the staged queue and its
 coalescing, query stat bookkeeping, the flush orchestration (delete scan ->
 batched device checkIns frontier -> fused purge+merge -> breadth-first
 repair with its changed-row frontier narrowing), persistence and the stats
-surface (including the per-phase flush timings) — lives
+surface (including the spans and the flush's transfer counts) — lives
 in ``EngineCore``. ``QueryEngine`` supplies the single-device table layout
 and device ops; ``repro.core.sharded.ShardedQueryEngine`` supplies the
 vertex-sharded multi-device layout on top of the same core, which is what
@@ -121,7 +129,6 @@ import contextlib
 import functools
 import json
 import os
-import time
 import zipfile
 import zlib
 from collections import OrderedDict
@@ -142,6 +149,7 @@ from repro.core.errors import (
 from repro.core.journal import UpdateJournal
 from repro.core.construct_jax import build_knn_tables_jax
 from repro.core.index import PAD_ID, KNNIndex
+from repro.core.spans import span
 from repro.core.updates import insert_affected_set
 from repro.analysis import sanitize
 from repro.kernels import ops
@@ -151,6 +159,8 @@ _FORMAT = "repro-knn-index"
 # artifacts unchanged (no checksum to verify) and refuses versions > 3.
 _FORMAT_VERSION = 3
 _MAX_REPAIR_ROUNDS = 256
+# a flush's host<->device transfers, counted by ``_readback`` / ``_upload``
+_IO_KEYS = ("readbacks", "readback_bytes", "uploads", "upload_bytes")
 
 
 def _tables_checksum(ids: np.ndarray, dists: np.ndarray, objects: np.ndarray) -> int:
@@ -305,10 +315,12 @@ class EngineCore:
             "rows_repaired": 0,
             "repair_rounds_last": 0,
             "frontier_rounds_last": 0,
-            "t_frontier_s": 0.0,
-            "t_purge_merge_s": 0.0,
-            "t_repair_s": 0.0,
+            **{"flush_" + key: 0 for key in _IO_KEYS},
         }
+        # host spans (``repro.core.spans``): name -> {"s": seconds, "n": calls}
+        self._span_totals: dict[str, dict] = {}
+        # the running flush's transfer counts (``_IO_KEYS``), else None
+        self._io: dict[str, int] | None = None
         # epoch-versioned serving state: epoch 0 is the constructor tables;
         # every flush publishes the next epoch and queries resolve their
         # snapshot at dispatch (see the module docstring)
@@ -576,14 +588,18 @@ class EngineCore:
         if us.ndim != 1:
             raise QueryError(f"queries must be a 1-D vertex array, got {us.shape}")
         epoch_r, snap = self._epochs.resolve(epoch)
-        with sanitize.guard("query"):
-            ks, width = self._ks_array(us.shape[0], k)
-            ids, d = self._gather_batch(us, ks, snap, epoch_r)
-        self._stats["queries_served"] += int(us.shape[0])
-        self._stats["query_batches"] += 1
-        self._stats["last_batch_size"] = int(us.shape[0])
-        if width < self.k:
-            ids, d = ids[:, :width], d[:, :width]
+        b = int(us.shape[0])
+        with self._span("query", batch=self._stats["query_batches"], epoch=epoch_r, b=b):
+            with sanitize.guard("query"):
+                with self._span("query.ks"):
+                    ks, width = self._ks_array(b, k)
+                with self._span("query.gather"):
+                    ids, d = self._gather_batch(us, ks, snap, epoch_r)
+            self._stats["queries_served"] += b
+            self._stats["query_batches"] += 1
+            self._stats["last_batch_size"] = b
+            if width < self.k:
+                ids, d = ids[:, :width], d[:, :width]
         return ids, d
 
     def query_progressive_batch(
@@ -693,8 +709,8 @@ class EngineCore:
         """Device (n+1, t) adjacency slice for one width bucket, cached."""
         if t not in self._nbr_by_t:
             self._nbr_by_t[t] = (
-                jax.device_put(self._nbr_ids[:, :t]),
-                jax.device_put(self._nbr_w[:, :t]),
+                self._upload(self._nbr_ids[:, :t]),
+                self._upload(self._nbr_w[:, :t]),
             )
         return self._nbr_by_t[t]
 
@@ -707,7 +723,36 @@ class EngineCore:
         """
         out = np.full(_pow2_pad(len(rows), lo=64), self.n, np.int32)
         out[: len(rows)] = rows
-        return jax.device_put(out)
+        return self._upload(out)
+
+    # host spans and the flush's transfer counts -------------------------
+
+    def _span(self, name: str, **attrs):
+        """A ``knn:<name>`` span added to this engine's totals
+        (``stats()["spans"]``)."""
+        return span(name, self._span_totals, **attrs)
+
+    def _span_s(self, name: str) -> float:
+        tot = self._span_totals.get("knn:" + name)
+        return tot["s"] if tot else 0.0
+
+    def _readback(self, x: jax.Array) -> np.ndarray:
+        """Every blocking device->host readback of the flush path: one
+        ``knn:flush.readback`` span (the host waiting on the device) and
+        one count with its bytes."""
+        with self._span("flush.readback", bytes=int(x.nbytes)):
+            out = np.asarray(x)
+        if self._io is not None:
+            self._io["readbacks"] += 1
+            self._io["readback_bytes"] += out.nbytes
+        return out
+
+    def _upload(self, x, sharding=None) -> jax.Array:
+        """An explicit host->device upload, counted while a flush runs."""
+        if self._io is not None:
+            self._io["uploads"] += 1
+            self._io["upload_bytes"] += x.nbytes
+        return jax.device_put(x, sharding)
 
     # hooks the flush pipeline drives -----------------------------------
 
@@ -804,18 +849,21 @@ class EngineCore:
         active = rows
         rounds = 0
         while active.size and rounds < _MAX_REPAIR_ROUNDS:
-            changed_parts = []
-            for part in self._bucket_parts(active):
-                changed_mask = self._repair_part(part)
-                changed_parts.append(part[changed_mask[: part.size]])
             rounds += 1
-            self._checkpoint("mid-repair-round")
-            changed_rows = (
-                np.concatenate(changed_parts) if changed_parts else np.empty(0, np.int32)
-            )
-            if changed_rows.size == 0:
-                break
-            active = self._repair_receivers(changed_rows, rows)
+            with self._span("flush.repair.round", round=rounds, rows=int(active.size)):
+                changed_parts = []
+                for part in self._bucket_parts(active):
+                    changed_mask = self._repair_part(part)
+                    changed_parts.append(part[changed_mask[: part.size]])
+                self._checkpoint("mid-repair-round")
+                changed_rows = (
+                    np.concatenate(changed_parts)
+                    if changed_parts
+                    else np.empty(0, np.int32)
+                )
+                if changed_rows.size == 0:
+                    break
+                active = self._repair_receivers(changed_rows, rows)
         else:
             if active.size:
                 raise RuntimeError(
@@ -862,14 +910,15 @@ class EngineCore:
         touched = [active]
         rounds = 0
         while active.size and rounds < _MAX_REPAIR_ROUNDS:
-            nbrs = self._expand_receivers(active)
-            state, changed_parts = self._frontier_round(state, nbrs)
             rounds += 1
-            active = (
-                np.concatenate(changed_parts)
-                if changed_parts
-                else np.empty(0, np.int32)
-            )
+            with self._span("flush.frontier.round", round=rounds, rows=int(active.size)):
+                nbrs = self._expand_receivers(active)
+                state, changed_parts = self._frontier_round(state, nbrs)
+                active = (
+                    np.concatenate(changed_parts)
+                    if changed_parts
+                    else np.empty(0, np.int32)
+                )
             if active.size:
                 touched.append(active)
         if active.size:
@@ -1006,11 +1055,14 @@ class EngineCore:
         deletion holes with breadth-first Jacobi rounds that source- and
         destination-side work share. Returns the per-flush stats dict (net
         insert/delete/move counts plus ``coalesced``, the staged ops the
-        folding eliminated, and the frontier/repair round counts); the
-        cumulative per-phase wall times land in ``stats()`` as
-        ``t_frontier_s`` / ``t_purge_merge_s`` / ``t_repair_s``.
+        folding eliminated, and the frontier/repair round counts).
+
+        Each phase runs in a ``knn:flush.*`` span (``repro.core.spans``);
+        ``stats()`` carries the span totals (``t_frontier_s`` and
+        ``t_repair_s`` read them) and the cumulative transfer counts
+        (``flush_readbacks`` ...), and ``epoch_stats(e)`` the flush's own
+        (``t_wall_s``, ``readbacks`` ...).
         """
-        t_wall0 = time.perf_counter()
         staged = len(self._staged)
         del_set = self._objects - self._pending
         ins_set = self._pending - self._objects
@@ -1019,116 +1071,44 @@ class EngineCore:
         moves = self._coalesced_moves(del_set, ins_set)
         n_pure_ins = len(inserts) - len(moves)
         n_pure_del = len(deletes) - len(moves)
-
-        # Epoch e+1 is built on the working references; the published epoch
-        # e snapshot keeps its own references to the old buffers, so queries
-        # dispatched anywhere in here still read a whole epoch. Any failure
-        # (a device error, or a chaos hook's simulated kill) rolls the
-        # working references back to epoch e with the staged queue intact —
-        # the flush is retryable and serving never stops.
-        base = self._epochs.snapshot()
-        # Sanitizer rail: the device flush pipeline runs under the transfer
-        # guard (all uploads must be explicit device_puts); the "host"
-        # frontier is the measured host baseline, exempt by definition.
-        flush_guard = (
-            sanitize.guard("flush")
-            if self._frontier == "device"
-            else contextlib.nullcontext()
-        )
-        try:
-            with flush_guard:
-                # -- delete side: which rows name a deleted object (device scan) --
-                purged_rows = np.empty(0, np.int32)
-                if deletes:
-                    purged_rows = self._scan_delete_rows(deletes)
-
-                # -- insert side: batched checkIns frontier, insert-first semantics --
-                # The frontier prunes against the CURRENT (pre-update) k-th bounds,
-                # exactly Algorithm 4 run before Algorithm 5 (the same order the
-                # scalar ``move_object`` oracle uses). A row the pruning misses that
-                # still needs a new object in the *final* tables must have had its
-                # k-th distance raised by the deletions — i.e. it lost an entry, so
-                # it is in the purge set and the repair rounds rebuild it from its
-                # bridge neighbors anyway. Keeping the pre-update bounds keeps the
-                # frontier as tight as the oracle's, instead of the unpruned sweep a
-                # post-purge (unbounded) k-th would trigger.
-                t0 = time.perf_counter()
-                f_rounds = 0
-                frows = np.empty(0, np.int32)
-                fc_ids = fc_d = None
-                if inserts:
-                    provider = (
-                        self._insert_frontier_host
-                        if self.frontier == "host"
-                        else self._insert_frontier
-                    )
-                    frows, fc_ids, fc_d, f_rounds = provider(inserts)
-                t_frontier = time.perf_counter() - t0
-
-                # -- one fused purge + merge over the union of both row sets --
-                rounds = 0
-                t_purge = t_repair = 0.0
-                if purged_rows.size or frows.size:
-                    t0 = time.perf_counter()
-                    rows = np.union1d(purged_rows, frows).astype(np.int32)
-                    p = fc_ids.shape[1] if frows.size else 1
-                    cand_ids = np.full((len(rows), p), -1, np.int32)
-                    cand_d = np.full((len(rows), p), np.inf, np.float32)
-                    if frows.size:
-                        pos = np.searchsorted(rows, frows)
-                        cand_ids[pos] = fc_ids
-                        cand_d[pos] = fc_d
-                    self._purge_merge(rows, deletes, cand_ids, cand_d)
-                    t_purge = time.perf_counter() - t0
-                    # -- breadth-first repair of the deletion holes (shared frontier) --
-                    if purged_rows.size:
-                        t0 = time.perf_counter()
-                        rounds = self._repair(purged_rows)
-                        t_repair = time.perf_counter() - t0
-
-                # -- staged layout changes (repartition-on-flush) ride the
-                # same epoch: the hook re-lays the working tables so the
-                # publish below swaps tables AND layout atomically
-                self._prepare_publish()
-            self._checkpoint("pre-swap")
-        except BaseException:
-            self._restore_tables(base)
-            self._stats["flushes_failed"] += 1
-            raise
-
-        # -- atomic swap: publish epoch e+1, commit the journal segment --
-        self._objects = set(self._pending)
-        self._staged.clear()
         new_epoch = self.epoch + 1
-        self._publish_epoch(new_epoch)
-        if self._journal is not None:
-            self._journal.commit(new_epoch)
+
+        self._io = dict.fromkeys(_IO_KEYS, 0)
+        try:
+            with self._span("flush", epoch=new_epoch, staged=staged,
+                            inserts=len(inserts), deletes=len(deletes)) as sp:
+                purged, merged, rounds, f_rounds = self._apply_delta(
+                    deletes, inserts, new_epoch
+                )
+        finally:
+            io, self._io = self._io, None
+            for key, value in io.items():
+                self._stats["flush_" + key] += value
+
         self._stats["flushes"] += 1
         self._stats["inserts_applied"] += n_pure_ins
         self._stats["deletes_applied"] += n_pure_del
         self._stats["moves_applied"] += len(moves)
         self._stats["coalesced"] += staged - (n_pure_ins + n_pure_del + len(moves))
-        self._stats["rows_repaired"] += int(purged_rows.size) + int(frows.size)
+        self._stats["rows_repaired"] += purged + merged
         self._stats["repair_rounds_last"] = rounds
         self._stats["frontier_rounds_last"] = f_rounds
-        self._stats["t_frontier_s"] += t_frontier
-        self._stats["t_purge_merge_s"] += t_purge
-        self._stats["t_repair_s"] += t_repair
         result = {
             "staged": staged,
             "inserts": n_pure_ins,
             "deletes": n_pure_del,
             "moves": len(moves),
             "coalesced": staged - (n_pure_ins + n_pure_del + len(moves)),
-            "rows_purged": int(purged_rows.size),
-            "rows_merged": int(frows.size),
+            "rows_purged": purged,
+            "rows_merged": merged,
             "repair_rounds": rounds,
             "frontier_rounds": f_rounds,
         }
         self._epoch_stats[new_epoch] = {
             "origin": "flush",
             "flush": dict(result),
-            "t_wall_s": time.perf_counter() - t_wall0,
+            "t_wall_s": sp.s,
+            **io,
         }
         self._trim_epoch_stats()
         self._checkpoint("post-swap")
@@ -1138,6 +1118,99 @@ class EngineCore:
                 ids_h, d_h, self.n, context=f"flush -> epoch {new_epoch}"
             )
         return result
+
+    def _flush_guard(self):
+        """Sanitizer rail: the device flush pipeline runs under the transfer
+        guard (all uploads must be explicit device_puts); the "host"
+        frontier is the measured host baseline, exempt by definition."""
+        if self._frontier == "device":
+            return sanitize.guard("flush")
+        return contextlib.nullcontext()
+
+    @contextlib.contextmanager
+    def _rollback_on_failure(self, base: tuple):
+        """Any failure (a device error, or a chaos hook's simulated kill)
+        rolls the working references back to the published epoch ``base``
+        with the staged queue intact — the flush is retryable and serving
+        never stops."""
+        try:
+            yield
+        except BaseException:
+            self._restore_tables(base)
+            self._stats["flushes_failed"] += 1
+            raise
+
+    def _apply_delta(
+        self, deletes: list[int], inserts: list[int], new_epoch: int
+    ) -> tuple[int, int, int, int]:
+        """The flush's phases, then the publish of ``new_epoch``. Returns
+        (rows purged, rows merged, repair rounds, frontier rounds)."""
+        # Epoch e+1 is built on the working references; the published epoch
+        # e snapshot keeps its own references to the old buffers, so queries
+        # dispatched anywhere in here still read a whole epoch.
+        base = self._epochs.snapshot()
+        with self._rollback_on_failure(base), self._flush_guard():
+            # -- delete side: which rows name a deleted object (device scan) --
+            purged_rows = np.empty(0, np.int32)
+            if deletes:
+                with self._span("flush.scan"):
+                    purged_rows = self._scan_delete_rows(deletes)
+
+            # -- insert side: batched checkIns frontier, insert-first semantics --
+            # The frontier prunes against the CURRENT (pre-update) k-th bounds,
+            # exactly Algorithm 4 run before Algorithm 5 (the same order the
+            # scalar ``move_object`` oracle uses). A row the pruning misses that
+            # still needs a new object in the *final* tables must have had its
+            # k-th distance raised by the deletions — i.e. it lost an entry, so
+            # it is in the purge set and the repair rounds rebuild it from its
+            # bridge neighbors anyway. Keeping the pre-update bounds keeps the
+            # frontier as tight as the oracle's, instead of the unpruned sweep a
+            # post-purge (unbounded) k-th would trigger.
+            f_rounds = 0
+            frows = np.empty(0, np.int32)
+            fc_ids = fc_d = None
+            if inserts:
+                provider = (
+                    self._insert_frontier_host
+                    if self.frontier == "host"
+                    else self._insert_frontier
+                )
+                with self._span("flush.frontier"):
+                    frows, fc_ids, fc_d, f_rounds = provider(inserts)
+
+            # -- one fused purge + merge over the union of both row sets --
+            rounds = 0
+            if purged_rows.size or frows.size:
+                with self._span("flush.purge_merge"):
+                    rows = np.union1d(purged_rows, frows).astype(np.int32)
+                    p = fc_ids.shape[1] if frows.size else 1
+                    cand_ids = np.full((len(rows), p), -1, np.int32)
+                    cand_d = np.full((len(rows), p), np.inf, np.float32)
+                    if frows.size:
+                        pos = np.searchsorted(rows, frows)
+                        cand_ids[pos] = fc_ids
+                        cand_d[pos] = fc_d
+                    self._purge_merge(rows, deletes, cand_ids, cand_d)
+                # -- breadth-first repair of the deletion holes (shared frontier) --
+                if purged_rows.size:
+                    with self._span("flush.repair"):
+                        rounds = self._repair(purged_rows)
+
+        # -- staged layout changes (repartition-on-flush) ride the same
+        # epoch: the hook re-lays the working tables so the swap below
+        # publishes tables AND layout atomically; then the swap and the
+        # journal segment's commit
+        with self._span("flush.publish"):
+            with self._rollback_on_failure(base):
+                with self._flush_guard():
+                    self._prepare_publish()
+                self._checkpoint("pre-swap")
+            self._objects = set(self._pending)
+            self._staged.clear()
+            self._publish_epoch(new_epoch)
+            if self._journal is not None:
+                self._journal.commit(new_epoch)
+        return int(purged_rows.size), int(frows.size), rounds, f_rounds
 
     # ------------------------------------------------------------------
     # persistence / stats
@@ -1214,6 +1287,9 @@ class EngineCore:
             "epoch_table_bytes": len(retained) * self._table_bytes(),
             **self._extra_stats(),
             **self._stats,
+            "t_frontier_s": self._span_s("flush.frontier"),
+            "t_repair_s": self._span_s("flush.repair"),
+            "spans": {name: dict(tot) for name, tot in self._span_totals.items()},
         }
 
 
@@ -1342,12 +1418,12 @@ class QueryEngine(EngineCore):
         return ops.serve_gather(snap[0], snap[1], jax.device_put(us), ks)
 
     def _scan_delete_rows(self, deletes: list[int]) -> np.ndarray:
-        del_arr = jax.device_put(self._padded_deletes(deletes))
-        hit = np.asarray(ops.rows_containing(self._vk_ids, del_arr))
+        del_arr = self._upload(self._padded_deletes(deletes))
+        hit = self._readback(ops.rows_containing(self._vk_ids, del_arr))
         return np.flatnonzero(hit).astype(np.int32)
 
     def _table_kth(self) -> np.ndarray:
-        return np.asarray(self._vk_d[: self.n, -1], np.float64)
+        return self._readback(self._vk_d[: self.n, -1]).astype(np.float64)
 
     def _purge_merge(self, rows, deletes, cand_ids, cand_d) -> None:
         r_pad = _pow2_pad(len(rows), lo=64)  # must match _pad_rows
@@ -1356,8 +1432,8 @@ class QueryEngine(EngineCore):
         cand_d = np.pad(cand_d, pad, constant_values=np.inf)
         self._vk_ids, self._vk_d = ops.rows_purge_merge(
             self._vk_ids, self._vk_d, self._pad_rows(rows),
-            jax.device_put(self._padded_deletes(deletes)),
-            jax.device_put(cand_ids), jax.device_put(cand_d), self.k,
+            self._upload(self._padded_deletes(deletes)),
+            self._upload(cand_ids), self._upload(cand_d), self.k,
             use_pallas=self.use_pallas,
         )
 
@@ -1366,7 +1442,7 @@ class QueryEngine(EngineCore):
         self._vk_ids, self._vk_d, changed_mask = _repair_round(
             nbr_tab, w_tab, self._pad_rows(part), self._vk_ids, self._vk_d
         )
-        return np.asarray(changed_mask)
+        return self._readback(changed_mask)
 
     # frontier provider (single-device layout): the multi-source tentative
     # distance state is one (n+1, B) device matrix; the pruning column is
@@ -1374,7 +1450,7 @@ class QueryEngine(EngineCore):
     # no kth values ever cross the host boundary.
 
     def _frontier_init(self, src: np.ndarray) -> jax.Array:
-        self._fsrc = jax.device_put(self._frontier_pad_src(src))
+        self._fsrc = self._upload(self._frontier_pad_src(src))
         return _frontier_init_prog(self._fsrc, self._vk_ids.shape[0])
 
     def _frontier_part(self, state, part: np.ndarray):
@@ -1383,12 +1459,12 @@ class QueryEngine(EngineCore):
             nbr_tab, w_tab, self._pad_rows(part), state, self._vk_d,
             self._fsrc, self.use_pallas,
         )
-        return state, np.asarray(changed)
+        return state, self._readback(changed)
 
     def _frontier_extract(self, state, rows: np.ndarray, src: np.ndarray):
         aff, d = _frontier_affected(self._pad_rows(rows), state, self._vk_d, self._fsrc)
         b = len(src)
-        return np.asarray(aff)[: len(rows), :b], np.asarray(d)[: len(rows), :b]
+        return self._readback(aff)[: len(rows), :b], self._readback(d)[: len(rows), :b]
 
     def _host_tables(self) -> tuple[np.ndarray, np.ndarray]:
         return np.asarray(self._vk_ids[: self.n]), np.asarray(self._vk_d[: self.n])

@@ -1398,11 +1398,11 @@ class ShardedQueryEngine(EngineCore):
     def _put_shard(self, x) -> jax.Array:
         """Upload splitting the leading axis across shards."""
         spec = P("shard", *([None] * (np.ndim(x) - 1)))
-        return jax.device_put(x, NamedSharding(self.mesh, spec))
+        return self._upload(x, NamedSharding(self.mesh, spec))
 
     def _put_repl(self, x) -> jax.Array:
         """Upload (or re-place) fully replicated across the mesh."""
-        return jax.device_put(x, NamedSharding(self.mesh, P()))
+        return self._upload(x, NamedSharding(self.mesh, P()))
 
     # ------------------------------------------------------------------
     # host-side routing (queries batched per shard, one roundtrip)
@@ -1614,7 +1614,7 @@ class ShardedQueryEngine(EngineCore):
         gi, gd = self._gather_fn(
             self._ids_g, self._d_g, self._put_shard(qglob), self._put_repl(fidx), ks
         )
-        return np.asarray(gi)[:m], np.asarray(gd)[:m]
+        return self._readback(gi)[:m], self._readback(gd)[:m]
 
     # ------------------------------------------------------------------
     # flush hooks (per-shard application)
@@ -1626,7 +1626,7 @@ class ShardedQueryEngine(EngineCore):
         # vertex starts[s] + j while j < widths[s] (rows past a shard's
         # range width are all-pad under uneven ranges, never hit — but the
         # map back to vertex ids must still go through the boundaries)
-        hits = np.asarray(self._scan_fn(self._ids_g, del_arr))
+        hits = self._readback(self._scan_fn(self._ids_g, del_arr))
         hits = hits.reshape(self.num_shards, -1)
         lay = self.routing.current_layout
         s_idx, j_idx = np.nonzero(hits)
@@ -1634,7 +1634,7 @@ class ShardedQueryEngine(EngineCore):
         return (lay.starts[s_idx] + j_idx)[valid].astype(np.int32)
 
     def _table_kth(self) -> np.ndarray:
-        kth = np.asarray(self._kth_fn(self._d_g))
+        kth = self._readback(self._kth_fn(self._d_g))
         return kth[self._g_of_v].astype(np.float64)
 
     def _apply_rows(
@@ -1659,7 +1659,7 @@ class ShardedQueryEngine(EngineCore):
             self._put_repl(self._padded_deletes(deletes)),
             self._put_shard(ci), self._put_shard(cd),
         )
-        changed = np.asarray(changed)
+        changed = self._readback(changed)
         out = np.zeros(b, dtype=bool)
         out[order] = changed[o_sorted, slot]
         return out
@@ -1687,7 +1687,7 @@ class ShardedQueryEngine(EngineCore):
             self._ids_g, self._d_g, changed = _repair_round(
                 nbr_tab, w_tab, self._pad_rows(part), self._ids_g, self._d_g
             )
-            return np.asarray(changed)
+            return self._readback(changed)
         if self.halo == "collective":
             out = self._repair_part_collective(part)
             if out is not None:
@@ -1733,7 +1733,7 @@ class ShardedQueryEngine(EngineCore):
             self._put_shard(rglob), self._put_repl(self._padded_deletes([])),
         )
         self._halo_stats["halo_rounds_collective"] += 1
-        changed = np.asarray(changed)
+        changed = self._readback(changed)
         out = np.zeros(len(part), dtype=bool)
         out[order] = changed[o_sorted, slot]
         return out
@@ -1814,7 +1814,7 @@ class ShardedQueryEngine(EngineCore):
         masks, ok = self._fmask, self._fmask_ok
         self._fmask, self._fmask_ok = [], True  # arm for the coming round
         if masks and ok:
-            m = np.sum([np.asarray(x)[:-1] for x in masks], axis=0)
+            m = np.sum([self._readback(x)[:-1] for x in masks], axis=0)
             return np.flatnonzero(m).astype(np.int32)
         return self._expand_receivers_device(active)
 
@@ -1825,7 +1825,7 @@ class ShardedQueryEngine(EngineCore):
         unique. Exactly ``np.unique`` of the host CSR expansion — pinned
         by test."""
         aglob, _ = self._route(active)
-        mask = np.asarray(self._expand_fn(self._nbr_glob(), self._put_shard(aglob)))
+        mask = self._readback(self._expand_fn(self._nbr_glob(), self._put_shard(aglob)))
         return np.flatnonzero(mask[:-1]).astype(np.int32)
 
     def _repair_receivers(
@@ -1845,7 +1845,7 @@ class ShardedQueryEngine(EngineCore):
     def _frontier_init(self, src: np.ndarray):
         self._fmask, self._fmask_ok = None, True  # round 1 expands standalone
         srcp = self._frontier_pad_src(src)
-        self._fsrc = jnp.asarray(srcp)  # vertex ids (the 1-shard scalar path)
+        self._fsrc = self._upload(srcp)  # vertex ids (the 1-shard scalar path)
         grow = np.full(srcp.shape, -1, np.int64)
         m = srcp >= 0
         grow[m] = self._g_of_v[srcp[m]]
@@ -1877,7 +1877,7 @@ class ShardedQueryEngine(EngineCore):
                 nbr_tab, w_tab, self._pad_rows(part), state, self._d_g,
                 self._fsrc, self.use_pallas,
             )
-            return state, np.asarray(changed)
+            return state, self._readback(changed)
         if self.halo == "collective":
             out = self._frontier_part_collective(state, part)
             if out is not None:
@@ -1912,7 +1912,7 @@ class ShardedQueryEngine(EngineCore):
         self._halo_stats["halo_rounds_collective"] += 1
 
         def resolve(changed=changed, order=order, o_sorted=o_sorted, slot=slot):
-            cm = np.asarray(changed)
+            cm = self._readback(changed)
             out = np.zeros(len(part), dtype=bool)
             out[order] = cm[o_sorted, slot]
             return out
@@ -1967,7 +1967,7 @@ class ShardedQueryEngine(EngineCore):
         self._halo_stats["halo_rounds_collective"] += len(parts)
         changed_parts = []
         for ch, (part, order, o_sorted, slot) in zip(chs, maps):
-            cm = np.asarray(ch)
+            cm = self._readback(ch)
             out = np.zeros(len(part), dtype=bool)
             out[order] = cm[o_sorted, slot]
             changed_parts.append(part[out])
@@ -2013,7 +2013,7 @@ class ShardedQueryEngine(EngineCore):
             self._d_g, state, self._put_shard(qglob), self._put_repl(fidx),
             self._fsrc_g,
         )
-        return np.asarray(out)[:m]
+        return self._readback(out)[:m]
 
     def _apply_fmin(self, state, rows: np.ndarray, vals: np.ndarray):
         """Split a receiver batch by owner shard and run the per-shard
@@ -2030,7 +2030,7 @@ class ShardedQueryEngine(EngineCore):
         state, changed = self._fmin_fn(
             state, self._put_shard(rglob), self._put_shard(vv)
         )
-        changed = np.asarray(changed)
+        changed = self._readback(changed)
         out = np.zeros(len(rows), dtype=bool)
         out[order] = changed[o_sorted, slot]
         return state, out
@@ -2043,8 +2043,8 @@ class ShardedQueryEngine(EngineCore):
                 self._pad_rows(rows), state, self._d_g, self._fsrc
             )
             return (
-                np.asarray(aff)[: len(rows), : len(src)],
-                np.asarray(d)[: len(rows), : len(src)],
+                self._readback(aff)[: len(rows), : len(src)],
+                self._readback(d)[: len(rows), : len(src)],
             )
         m = len(rows)
         m_pad = _pow2_pad(m, lo=64)
@@ -2055,7 +2055,7 @@ class ShardedQueryEngine(EngineCore):
             self._d_g, state, self._put_shard(qglob), self._put_repl(fidx),
             self._fsrc_g,
         )
-        return np.asarray(aff)[:m, : len(src)], np.asarray(d)[:m, : len(src)]
+        return self._readback(aff)[:m, : len(src)], self._readback(d)[:m, : len(src)]
 
     # ------------------------------------------------------------------
     # persistence / stats
