@@ -303,9 +303,8 @@ def check_kernel_poisoning(*, k: int = 4, seed: int = 0, interpret: bool = True)
     pad slot or a non-Jacobi read shows up as an exact-equality miss vs the
     oracle.
     """
-    from repro.kernels import ref
+    from repro.kernels import ops, ref
     from repro.kernels.frontier_relax import frontier_relax_pallas
-    from repro.kernels.sweep_merge import sweep_merge_pallas
 
     rng = np.random.default_rng(seed)
     trap = np.float32(7e7)  # finite, absurd, impossible to produce legally
@@ -333,11 +332,11 @@ def check_kernel_poisoning(*, k: int = 4, seed: int = 0, interpret: bool = True)
         jnp.asarray(ex_ids), jnp.asarray(ex_d),
         jnp.asarray(vk_ids), jnp.asarray(vk_d), k=k,
     )
-    got = sweep_merge_pallas(
+    got = ops.sweep_merge(
         jnp.asarray(nbr), jnp.asarray(verts), jnp.asarray(w),
         jnp.asarray(ex_ids), jnp.asarray(ex_d),
         jnp.asarray(vk_ids), jnp.asarray(vk_d),
-        k=k, interpret=interpret,
+        k, use_pallas=True, interpret=interpret,
     )
     for name, g, wnt in (("ids", got[0], want[0]), ("dists", got[1], want[1])):
         g = np.asarray(g)

@@ -12,9 +12,12 @@ This module runs the whole sweep as a *fused, device-resident schedule*:
 * ``prepare_sweep`` packs every level's ``verts``/``nbr``/``w`` into a small
   number of flat, contiguous device arrays — one set per (T, CHUNK) shape
   bucket — plus two tiny index arrays naming, for each fixed-size row chunk,
-  which bucket it lives in and at which row offset. The entire schedule is
-  uploaded **once** per sweep (explicit ``jax.device_put``); nothing else
-  crosses the host/device boundary until the final result readback.
+  which bucket it lives in and at which row offset. ``nbr``/``w`` are kept
+  1-D (rows flattened): a 1-D array has one device layout, so the TPU
+  compiler has no whole-bucket relayout to sink into the loop's branches.
+  The entire schedule is uploaded **once** per sweep (explicit
+  ``jax.device_put``); nothing else crosses the host/device boundary until
+  the final result readback.
   Ragged-aware bucketing (power-of-4 neighbor widths, capped at the global
   max, two chunk tiers) caps padding waste; the plan reports ``occupancy``
   for the flat layout next to ``occupancy_levelwise`` for the seed's
@@ -23,14 +26,17 @@ This module runs the whole sweep as a *fused, device-resident schedule*:
 * ``run_sweep`` executes one direction as a **single jitted program**: a
   ``lax.fori_loop`` over chunks whose body ``lax.switch``es into one branch
   per shape bucket. Each branch dynamic-slices its chunk out of the flat
-  schedule and applies ``ops.sweep_merge`` — on the Pallas path a single
-  fused kernel per chunk that gathers neighbor k-lists straight out of the
-  live HBM V_k tables into VMEM, shifts, merges (k rounds of dedup
-  min-selection) and scatters the result rows, never materialising the
-  (S, T*k + E) candidate tensor; on the XLA path the same math with an
-  explicit candidate tensor. Distinct compilations per build are bounded by
-  the number of shape-bucket signatures (one program per sweep), not by the
-  number of levels.
+  schedule and applies ``ops.sweep_merge_rows`` — on the Pallas path a
+  single fused kernel per chunk that gathers neighbor k-lists straight out
+  of the live HBM V_k tables into VMEM, shifts and merges (k rounds of dedup
+  min-selection), never materialising the (S, T*k + E) candidate tensor; on
+  the XLA path the same math with an explicit candidate tensor. The
+  branches only read the tables and return the merged rows; the loop body
+  scatters them into the V_k carry once per chunk, outside the switch, so
+  the write is in place (a write inside a branch copies both whole tables,
+  since a conditional's operand cannot alias its result). Distinct
+  compilations per build are bounded by the number of shape-bucket
+  signatures (one program per sweep), not by the number of levels.
 
 * ``build_knn_index_jax`` chains the two sweeps entirely on device: the
   bottom-up result tables (V_k^<, including the dummy padding row) are handed
@@ -78,8 +84,8 @@ class SweepBucket:
     t_pad: int
     chunk: int
     verts: jax.Array  # (R,) int32, padded rows hold n (the dummy row id)
-    nbr: jax.Array    # (R, t_pad) int32, padded slots hold -1
-    w: jax.Array      # (R, t_pad) float32, padded slots hold +inf
+    nbr: jax.Array    # (R * t_pad,) int32, rows flattened, padded slots hold -1
+    w: jax.Array      # (R * t_pad,) float32, rows flattened, padded slots hold +inf
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,8 +166,8 @@ def prepare_sweep(bn: BNGraph, direction: str) -> SweepPlan:
                 t_pad=key[0],
                 chunk=key[1],
                 verts=jax.device_put(np.concatenate(b["verts"])),
-                nbr=jax.device_put(np.concatenate(b["nbr"])),
-                w=jax.device_put(np.concatenate(b["w"])),
+                nbr=jax.device_put(np.concatenate(b["nbr"]).reshape(-1)),
+                w=jax.device_put(np.concatenate(b["w"]).reshape(-1)),
             )
         )
     return SweepPlan(
@@ -191,19 +197,30 @@ def _sweep_program(
     interpret: bool | None,
 ):
     """One full sweep as a single XLA program: fori_loop over chunks, switch
-    over shape buckets. The V_k carry lives in HBM for the whole loop."""
+    over shape buckets. The V_k carry lives in HBM for the whole loop.
+
+    Each branch returns its chunk's target rows and merged rows, padded to
+    the widest CHUNK with row n + 1, which the in-place scatter in the loop
+    body drops (see the module docstring for why the write is not in the
+    branch).
+    """
     vk_ids = jnp.full((n + 1, k), -1, jnp.int32)
     vk_d = jnp.full((n + 1, k), jnp.inf, jnp.float32)
+    width = max(chunks)
 
     def make_branch(bverts, bnbr, bw, chunk):
         def branch(off, vk_ids, vk_d):
             verts = jax.lax.dynamic_slice_in_dim(bverts, off, chunk)
-            nbr = jax.lax.dynamic_slice_in_dim(bnbr, off, chunk)
-            w = jax.lax.dynamic_slice_in_dim(bw, off, chunk)
-            return ops.sweep_merge(
+            t = bnbr.shape[0] // bverts.shape[0]
+            nbr = jax.lax.dynamic_slice_in_dim(bnbr, off * t, chunk * t).reshape(chunk, t)
+            w = jax.lax.dynamic_slice_in_dim(bw, off * t, chunk * t).reshape(chunk, t)
+            m_ids, m_d = ops.sweep_merge_rows(
                 nbr, verts, w, ex_ids, ex_d, vk_ids, vk_d, k,
                 use_pallas=use_pallas, interpret=interpret,
             )
+            pad = ((0, width - chunk), (0, 0))
+            return (jnp.pad(verts, pad[:1], constant_values=n + 1),
+                    jnp.pad(m_ids, pad), jnp.pad(m_d, pad))
         return branch
 
     branches = [
@@ -213,9 +230,11 @@ def _sweep_program(
 
     def body(c, carry):
         vk_ids, vk_d = carry
-        return jax.lax.switch(
+        verts, m_ids, m_d = jax.lax.switch(
             chunk_bucket[c], branches, chunk_off[c], vk_ids, vk_d
         )
+        return (vk_ids.at[verts].set(m_ids, mode="drop"),
+                vk_d.at[verts].set(m_d, mode="drop"))
 
     return jax.lax.fori_loop(0, chunk_bucket.shape[0], body, (vk_ids, vk_d))
 

@@ -66,7 +66,7 @@ def topk_merge(
     return oid[:b], od[:b]
 
 
-def sweep_merge(
+def sweep_merge_rows(
     nbr: jax.Array,
     verts: jax.Array,
     w: jax.Array,
@@ -79,14 +79,15 @@ def sweep_merge(
     use_pallas: bool = True,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused construction step: gather + shift + dedup top-k + scatter.
+    """Fused construction step without its write: gather + shift + dedup top-k.
 
-    Updates rows ``verts`` of the live (n+1, k) V_k tables from the k-lists of
-    the neighbors in ``nbr`` (shifted by ``w``) merged with per-vertex extras.
-    Unlike the other wrappers this is a *trace-level* function, meant to be
-    called inside an already-jitted sweep loop (core/construct_jax.py), so it
-    does no padding or jit of its own: the caller guarantees the layout
-    invariants (padded slots -1/+inf, dummy row n).
+    Returns the merged (CHUNK, k) rows for ``verts``, built from the k-lists
+    of the neighbors in ``nbr`` (shifted by ``w``) merged with per-vertex
+    extras, and only reads the live (n+1, k) V_k tables. Unlike the other
+    wrappers this is a *trace-level* function, meant to be called inside an
+    already-jitted loop (core/construct_jax.py), so it does no padding or jit
+    of its own: the caller guarantees the layout invariants (padded slots
+    -1/+inf, dummy row n).
 
     The XLA form materialises the (CHUNK, T*k) gathered candidates and runs
     the same k-round merge; the Pallas path never materialises them (see
@@ -101,16 +102,40 @@ def sweep_merge(
         g_ids = g_ids.reshape(chunk, t * k)
         g_d = (w[..., None] + vk_d[nbr_c]).reshape(chunk, t * k)
         e_ids = ex_ids[verts]
-        m_ids, m_d = kround_merge(
+        return kround_merge(
             [(g_ids, jnp.where(g_ids < 0, jnp.inf, g_d)),
              (e_ids, jnp.where(e_ids < 0, jnp.inf, ex_d[verts].astype(jnp.float32)))],
             k,
         )
-        return vk_ids.at[verts].set(m_ids), vk_d.at[verts].set(m_d)
     itp = (not _on_tpu()) if interpret is None else interpret
     return sweep_merge_pallas(
         nbr, verts, w, ex_ids, ex_d, vk_ids, vk_d, k=k, interpret=itp
     )
+
+
+def sweep_merge(
+    nbr: jax.Array,
+    verts: jax.Array,
+    w: jax.Array,
+    ex_ids: jax.Array,
+    ex_d: jax.Array,
+    vk_ids: jax.Array,
+    vk_d: jax.Array,
+    k: int,
+    *,
+    use_pallas: bool = True,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """``sweep_merge_rows`` plus its write: returns the updated (vk_ids, vk_d).
+
+    A trace-level function like ``sweep_merge_rows``. The scatter yields new
+    tables, so every read of the step sees the pre-step tables (Jacobi).
+    """
+    m_ids, m_d = sweep_merge_rows(
+        nbr, verts, w, ex_ids, ex_d, vk_ids, vk_d, k,
+        use_pallas=use_pallas, interpret=interpret,
+    )
+    return vk_ids.at[verts].set(m_ids), vk_d.at[verts].set(m_d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k", "use_pallas", "interpret"))

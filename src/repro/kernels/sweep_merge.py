@@ -26,9 +26,10 @@ ids that stay — so the streamed merge equals the one-shot merge of the whole
 candidate row, bit for bit. At the last column the row lands in its
 (8, k) output tile, which the pipeline writes back once all 8 rows are done.
 
-The kernel emits the merged (CHUNK, k) tile; the wrapper scatters it into the
-tables in XLA. The kernel therefore only ever reads the pre-step tables and
-has no aliased operand to get wrong.
+The kernel emits the merged (CHUNK, k) tile; its caller scatters it into the
+tables in XLA (``ops.sweep_merge``, or once per chunk in the sweep loop). The
+kernel therefore only ever reads the pre-step tables and has no aliased
+operand to get wrong.
 
 Padded rows use vertex id n (the dummy row) and padded neighbor slots use -1
 (their weight is ignored), exactly as in the XLA path.
@@ -152,7 +153,7 @@ def sweep_merge_pallas(
     k: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """One fused construction step; returns the updated (vk_ids, vk_d)."""
+    """One fused construction step; returns the merged (CHUNK, k) rows."""
     chunk, t = nbr.shape
     rows = -(-chunk // ROW_BLOCK) * ROW_BLOCK
     nbr_p = pad_rows(nbr, rows, -1)
@@ -167,4 +168,4 @@ def sweep_merge_pallas(
     ]
     m_ids = jnp.concatenate([p[0] for p in parts])[:chunk]
     m_d = jnp.concatenate([p[1] for p in parts])[:chunk]
-    return vk_ids.at[verts].set(m_ids), vk_d.at[verts].set(m_d)
+    return m_ids, m_d

@@ -1,6 +1,9 @@
 """Algorithm 2 / Algorithm 3 / JAX fused-sweep construction vs Dijkstra oracle."""
+import re
+
 import jax
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -12,7 +15,7 @@ from repro.core.construct_jax import (
     prepare_sweep,
     run_sweep,
 )
-from repro.core.index import indices_equivalent
+from repro.core.index import KNNIndex, indices_equivalent
 from repro.core.reference import dijkstra_cons, knn_index_cons, knn_index_cons_plus
 from repro.graph.generators import pick_objects, random_connected_graph, road_network
 
@@ -95,9 +98,56 @@ def test_run_sweep_zero_host_transfers():
     ref = knn_index_cons_plus(bn, objects, k)
     ids = np.asarray(vk_ids[: g.n])
     dists = np.where(ids >= 0, np.asarray(vk_d[: g.n], np.float64), np.inf)
-    from repro.core.index import KNNIndex
-
     assert indices_equivalent(ref, KNNIndex(ids=ids, dists=dists, k=k))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_sweep_padded_rows_write_nothing(use_pallas):
+    """Rows that pad a chunk, or pad a branch's rows to the widest CHUNK,
+    leave the dummy row n at (-1, +inf) and every real row as the reference
+    has it, on a plan whose levels do not fill their chunks in either tier."""
+    g = road_network(12, 12, seed=1)
+    objects = pick_objects(g.n, 0.2, seed=1)
+    bn = build_bngraph(g)
+    k = 6
+    plans = prepare_sweep(bn, "up"), prepare_sweep(bn, "down")
+    up = plans[0]
+    assert {c for _, c in up.bucket_signature()} == {
+        construct_jax.CHUNK_SMALL, construct_jax.CHUNK_LARGE}
+    assert any(s >= construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_LARGE
+               for s in up.level_sizes)
+    assert any(s < construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_SMALL
+               for s in up.level_sizes)
+    tables = object_extras(g.n, objects, k)
+    for plan in plans:
+        tables = run_sweep(plan, *tables, k, use_pallas=use_pallas)
+        ids, d = (np.asarray(x) for x in tables)
+        assert (ids[g.n] == -1).all() and np.isinf(d[g.n]).all()
+    dists = np.where(ids[: g.n] >= 0, d[: g.n].astype(np.float64), np.inf)
+    ref = knn_index_cons_plus(bn, objects, k)
+    assert indices_equivalent(ref, KNNIndex(ids=ids[: g.n], dists=dists, k=k))
+
+
+@pytest.mark.parametrize("direction", ["up", "down"])
+def test_sweep_program_copies_no_table(direction):
+    """The sweep loop writes each chunk's rows into the V_k carry in place:
+    the compiled program holds no copy of an (n+1, k) table."""
+    g = road_network(40, 40, seed=1)
+    bn = build_bngraph(g)
+    plan = prepare_sweep(bn, direction)
+    assert len({c for _, c in plan.bucket_signature()}) == 2  # both CHUNK tiers
+    k = 20
+    table = jax.ShapeDtypeStruct((g.n + 1, k), np.int32)
+    hlo = construct_jax._sweep_program_jit.lower(
+        tuple((b.verts, b.nbr, b.w) for b in plan.buckets),
+        plan.chunk_bucket, plan.chunk_off,
+        table, table.update(dtype=np.float32),
+        n=g.n, k=k, chunks=tuple(b.chunk for b in plan.buckets),
+        use_pallas=False, interpret=None,
+    ).compile().as_text()
+    copy = re.compile(rf"\[{g.n + 1},{k}\]\S* copy(-start)?\(")
+    assert "scatter" in hlo
+    assert not [line for line in hlo.splitlines() if copy.search(line)]
 
 
 def test_sweep_compilations_bounded_by_buckets():

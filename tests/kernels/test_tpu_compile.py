@@ -5,18 +5,21 @@ compiler's rules (block-shape tiling, lowerable primitives, fast-memory
 limits). These tests compile ``sweep_merge``, ``frontier_relax`` and
 ``topk_merge`` through their ``ops`` wrappers, with ``interpret=False``, for
 one chip of a described ``v5e:2x2`` topology at n = 2^20, k = 20, chunk 512
-and B = 512 — nothing runs, so no chip is needed. The topology is described
+and B = 512, and the whole construction sweep program at the same n and k —
+nothing runs, so no chip is needed. The topology is described
 only inside the module fixture below, never at import.
 """
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import construct_jax
 from repro.kernels import ops
 
 N, K, CHUNK, B = 1 << 20, 20, 512, 512
@@ -87,3 +90,34 @@ def test_topk_merge_compiles_for_tpu(one_chip, t):
 
     c = t * K + K
     _compile(topk_merge, one_chip, ((CHUNK, c), jnp.int32), ((CHUNK, c), jnp.float32))
+
+
+def test_sweep_program_loop_copies_no_table_on_tpu(one_chip):
+    """The XLA-form sweep loop copies no whole array on the chip: no (n+1, k)
+    table and no bucket of the schedule in any computation but the entry
+    (whose copies, once per sweep, only change the tables' layout)."""
+    tiers = ((4, 8), (16, 8), (16, 64), (64, 64))
+    rows, chunks = 4096, 256
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    buckets = tuple((sds((rows,), jnp.int32), sds((rows * t,), jnp.int32),
+                     sds((rows * t,), jnp.float32)) for t, _ in tiers)
+    hlo = construct_jax._sweep_program_jit.lower(
+        buckets, sds((chunks,), jnp.int32), sds((chunks,), jnp.int32),
+        sds((N + 1, K), jnp.int32), sds((N + 1, K), jnp.float32),
+        n=N, k=K, chunks=tuple(c for _, c in tiers),
+        use_pallas=False, interpret=False,
+    ).compile().as_text()
+    whole = {f"{N + 1},{K}"} | {f"{rows},{t}" for t, _ in tiers} | {
+        f"{rows * t}" for t, _ in tiers}
+    copy = re.compile(r"\[([\d,]+)\]\S* copy(-start)?\(")
+    in_entry, inner = False, []
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            in_entry = line.startswith("ENTRY")
+        elif not in_entry and (m := copy.search(line)) and m.group(1) in whole:
+            inner.append(line.strip())
+    assert " while(" in hlo
+    assert not inner
