@@ -112,6 +112,19 @@ Host spans: queries, flush phases and rounds, and the build run in named
 ``knn:*`` spans (``repro.core.spans``), written into a ``jax.profiler``
 trace when one is open and totalled in ``stats()["spans"]``.
 
+A closed program set: every flush program is jitted on the shapes of its
+arguments, and both engines pad each of them to a tier (``_rows_width``,
+``_cand_width``, the BNS width buckets, ``_src_widths`` for the frontier's
+sources and one width for the deletes: the delta cap, ``_delta_cap``). A
+delta past the cap is applied in successive deltas of at most the cap and
+published as one epoch. So ``QueryEngine`` can list every signature a
+flush can dispatch (``_flush_signatures``), and its first flush compiles
+them all (``_warm_flush``, the ``knn:flush.warm`` span): no later flush
+compiles, whatever the size or the spread of its delta
+(``stats()["flush_programs"]``). The sharded engine shares the tiers but
+lists no set: its multi-shard programs also pad per-shard counts (owner
+rows, halo sizes) that only the routing of a flush decides.
+
 Everything above that is *layout-independent* — the staged queue and its
 coalescing, query stat bookkeeping, the flush orchestration (delete scan ->
 batched device checkIns frontier -> fused purge+merge -> breadth-first
@@ -132,6 +145,7 @@ import os
 import zipfile
 import zlib
 from collections import OrderedDict
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator
 
 import jax
@@ -159,6 +173,16 @@ _FORMAT = "repro-knn-index"
 # artifacts unchanged (no checksum to verify) and refuses versions > 3.
 _FORMAT_VERSION = 3
 _MAX_REPAIR_ROUNDS = 256
+# the most staged inserts (and deletes) one delta of a flush applies: B,
+# the frontier's (n+1, B) float32 state, stays within 1/_STATE_SHARE of the
+# device's memory (a flush peaks near ten times the state: 2.7 GB at n =
+# 131,044 and B = 512 on a TPU v5e), and within _MAX_DELTA
+_MAX_DELTA = 512
+_STATE_SHARE = 24
+# the memory assumed where the device reports none (the CPU): a TPU v5e's
+_DEVICE_BYTES = 16 << 30
+# the most neighbor indices one gather of a repair round takes (``_repair_round``)
+_GATHER_INDICES = 1 << 15
 # a flush's host<->device transfers, counted by ``_readback`` / ``_upload``
 _IO_KEYS = ("readbacks", "readback_bytes", "uploads", "upload_bytes")
 
@@ -243,6 +267,24 @@ def _pow2_pad(x: int, lo: int = 8) -> int:
     return max(lo, 1 << (max(1, x) - 1).bit_length())
 
 
+def _tiers(width, limit: int) -> list[int]:
+    """Every value ``width(x)`` takes for 1 <= x <= limit, for a ``width``
+    that is constant between consecutive powers of two (and at the last
+    one up to ``limit``): the tiers a flush can pad a size of at most
+    ``limit`` to."""
+    if limit < 1:
+        return []
+    return sorted({width(min(1 << i, limit)) for i in range(limit.bit_length() + 1)})
+
+
+@functools.cache
+def _device_bytes() -> int:
+    """The first local device's memory, as it reports it, else
+    ``_DEVICE_BYTES``."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", _DEVICE_BYTES))
+
+
 class EngineCore:
     """Layout-independent serving core shared by the scalar and sharded engines.
 
@@ -321,6 +363,7 @@ class EngineCore:
         self._span_totals: dict[str, dict] = {}
         # the running flush's transfer counts (``_IO_KEYS``), else None
         self._io: dict[str, int] | None = None
+        self._flush_warmed = False  # see ``_warm_flush``
         # epoch-versioned serving state: epoch 0 is the constructor tables;
         # every flush publishes the next epoch and queries resolve their
         # snapshot at dispatch (see the module docstring)
@@ -714,14 +757,66 @@ class EngineCore:
             )
         return self._nbr_by_t[t]
 
-    def _pad_rows(self, rows: np.ndarray) -> jax.Array:
-        """Pad a row batch to a pow2 length with the dummy row id n.
+    # the flush's shape tiers ----------------------------------------------
 
-        lo=64 keeps the set of distinct jit row-count signatures small (64,
-        128, 256, ...) so a long-running service stops compiling after the
-        first few flushes; merging a few dozen dummy rows costs nothing.
-        """
-        out = np.full(_pow2_pad(len(rows), lo=64), self.n, np.int32)
+    def _delta_cap(self) -> int:
+        """The most staged inserts, and deletes, one delta of a flush
+        applies: the frontier's widest source width B and the one width of
+        the padded deletes; a larger flush applies successive deltas and
+        publishes once. The (n+1, B) float32 state within 1/_STATE_SHARE of
+        the device's memory, B at most ``_MAX_DELTA`` and no wider than n
+        needs; 128 at least on the Pallas path (lane-aligned columns)."""
+        lo = 128 if self.use_pallas else 8
+        fit = max(1, _device_bytes() // (_STATE_SHARE * 4 * (self.n + 1)))
+        return max(lo, min(_MAX_DELTA, 1 << (fit.bit_length() - 1), _pow2_pad(self.n, lo)))
+
+    def _src_widths(self) -> list[int]:
+        """The frontier's source widths: the delta cap and one an eighth of
+        it (at least the Pallas path's 128), so a small delta carries an
+        eighth of the state. Each width adds a set of frontier programs to
+        compile (about 60 on the benchmark's network), hence two."""
+        cap = self._delta_cap()
+        return sorted({max(cap // 8, 128 if self.use_pallas else 8), cap})
+
+    def _rows_width(self, r: int) -> int:
+        """Rows a batch of ``r`` is padded to: a power of two >= 64, at most
+        the table's n+1 rows. lo=64 keeps the set of distinct jit row-count
+        signatures small; merging a few dozen dummy rows costs nothing."""
+        return min(_pow2_pad(r, lo=64), self.n + 1)
+
+    def _cand_width(self, p: int) -> int:
+        """Candidate columns for rows with at most ``p`` affected inserts:
+        a power of four >= 4, at most the delta cap."""
+        w = 4
+        while w < p:
+            w *= 4
+        return min(w, self._delta_cap())
+
+    def _t_tiers(self) -> list[int]:
+        """The BNS-degree buckets a round splits its rows by (8/32/128/tau'),
+        see ``_bucket_parts``."""
+        cap = self._nbr_ids.shape[1]
+        return [b for b in (8, 32, 128) if b < cap] + [cap]
+
+    def _t_widths(self) -> list[tuple[int, int]]:
+        """(width, rows) for every width a part is dispatched at
+        (``_t_bucket``'s pow4 widths), with the most rows such a part holds:
+        the vertices of the part's bucket of degree at most that width."""
+        deg = self._nbr_deg[: self.n]
+        cap = self._nbr_ids.shape[1]
+        bounds = [0] + self._t_tiers()
+        out, t = [], 8
+        while True:
+            width = min(t, cap)
+            lo = max(b for b in bounds if b < width)
+            out.append((width, int(((deg > lo) & (deg <= width)).sum())))
+            if t >= cap:
+                return out
+            t *= 4
+
+    def _pad_rows(self, rows: np.ndarray) -> jax.Array:
+        """Pad a row batch to ``_rows_width`` with the dummy row id n."""
+        out = np.full(self._rows_width(len(rows)), self.n, np.int32)
         out[: len(rows)] = rows
         return self._upload(out)
 
@@ -757,12 +852,11 @@ class EngineCore:
     # hooks the flush pipeline drives -----------------------------------
 
     def _padded_deletes(self, deletes: list[int]) -> np.ndarray:
-        """Deleted-object ids pow2-padded with the dummy id n (never an
-        object id, so never a hit): bounds the distinct jit signatures
-        across flush sizes."""
-        if not deletes:
-            return np.full(1, self.n, np.int32)
-        padded = np.full(_pow2_pad(len(deletes)), self.n, np.int32)
+        """Deleted-object ids padded with the dummy id n (never an object
+        id, so never a hit) to the delta cap: one width, since it multiplies
+        the purge-merge's (rows, candidates) signatures, and a delete costs
+        the purge a compare per table entry of the rows it passes."""
+        padded = np.full(self._delta_cap(), self.n, np.int32)
         padded[: len(deletes)] = deletes
         return padded
 
@@ -804,7 +898,9 @@ class EngineCore:
         the collective path to fuse the round into one program."""
         pending = []
         for part in self._bucket_parts(nbrs):
-            state, changed_mask = self._frontier_part(state, part)
+            with self._span("flush.frontier.part", rows=int(part.size),
+                            t=self._t_bucket(part)):
+                state, changed_mask = self._frontier_part(state, part)
             pending.append((part, changed_mask))
         changed_parts = [
             p[(m() if callable(m) else m)[: p.size]] for p, m in pending
@@ -824,9 +920,8 @@ class EngineCore:
         partition identically (their round trajectories must match).
         """
         deg = self._nbr_deg[rows]
-        cap = self._nbr_ids.shape[1]
         prev = 0
-        for t in [b for b in (8, 32, 128) if b < cap] + [cap]:
+        for t in self._t_tiers():
             part = rows[(deg > prev) & (deg <= t)]
             prev = t
             if part.size:
@@ -853,7 +948,9 @@ class EngineCore:
             with self._span("flush.repair.round", round=rounds, rows=int(active.size)):
                 changed_parts = []
                 for part in self._bucket_parts(active):
-                    changed_mask = self._repair_part(part)
+                    with self._span("flush.repair.part", rows=int(part.size),
+                                    t=self._t_bucket(part)):
+                        changed_mask = self._repair_part(part)
                     changed_parts.append(part[changed_mask[: part.size]])
                 self._checkpoint("mid-repair-round")
                 changed_rows = (
@@ -873,13 +970,14 @@ class EngineCore:
         return rounds
 
     def _frontier_pad_src(self, src: np.ndarray) -> np.ndarray:
-        """Pad the staged-insert sources to a pow2 column count (-1 pads).
+        """Pad the staged-insert sources to the narrowest ``_src_widths``
+        column count that holds them (-1 pads).
 
         Bounds the distinct jit signatures across flush sizes, exactly like
         ``_pad_rows`` does for row batches; the Pallas relax kernel wants a
-        lane-aligned column count, so that path pads to 128 columns.
+        lane-aligned column count, so that path pads to 128 columns at least.
         """
-        b = _pow2_pad(len(src), lo=(128 if self.use_pallas else 8))
+        b = next(w for w in self._src_widths() if w >= len(src))
         out = np.full(b, -1, np.int32)
         out[: len(src)] = src
         return out
@@ -961,19 +1059,19 @@ class EngineCore:
         idx = np.repeat(starts - exc, counts) + np.arange(total)
         return np.unique(self._nbr_indices[idx]).astype(np.int32)
 
-    @staticmethod
     def _compact_candidates(
-        rows: np.ndarray, aff: np.ndarray, dvals: np.ndarray, src: np.ndarray
+        self, rows: np.ndarray, aff: np.ndarray, dvals: np.ndarray, src: np.ndarray
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """(touched rows, (R, B) affected mask + distances) -> the flush's
         per-row candidate arrays: affected columns compacted to the front in
-        source order, width pow2-padded — the exact layout the host frontier
-        builds, so ``_purge_merge`` sees identical inputs either way."""
+        source order, width padded to ``_cand_width`` — the exact layout the
+        host frontier builds, so ``_purge_merge`` sees identical inputs
+        either way."""
         keep = aff.any(axis=1)
         rows, aff, dvals = rows[keep], aff[keep], dvals[keep]
         if rows.size == 0:
             return rows, np.empty((0, 1), np.int32), np.empty((0, 1), np.float32)
-        p = _pow2_pad(int(aff.sum(axis=1).max()), lo=4)
+        p = self._cand_width(int(aff.sum(axis=1).max()))
         if p > aff.shape[1]:
             pad = ((0, 0), (0, p - aff.shape[1]))
             aff = np.pad(aff, pad)
@@ -1004,7 +1102,7 @@ class EngineCore:
         rows = np.fromiter(sorted(per_row), np.int32, len(per_row))
         if rows.size == 0:
             return rows, np.empty((0, 1), np.int32), np.empty((0, 1), np.float32), 0
-        p = _pow2_pad(max(len(c) for c in per_row.values()), lo=4)
+        p = self._cand_width(max(len(c) for c in per_row.values()))
         cand_ids = np.full((len(rows), p), -1, np.int32)
         cand_d = np.full((len(rows), p), np.inf, np.float32)
         for i, v in enumerate(rows.tolist()):
@@ -1057,6 +1155,11 @@ class EngineCore:
         insert/delete/move counts plus ``coalesced``, the staged ops the
         folding eliminated, and the frontier/repair round counts).
 
+        An engine's first flush first compiles every program a flush can
+        dispatch (``_warm_flush``), so no later flush compiles; a delta
+        larger than the delta cap is applied in successive deltas of at
+        most the cap, and published once.
+
         Each phase runs in a ``knn:flush.*`` span (``repro.core.spans``);
         ``stats()`` carries the span totals (``t_frontier_s`` and
         ``t_repair_s`` read them) and the cumulative transfer counts
@@ -1077,6 +1180,9 @@ class EngineCore:
         try:
             with self._span("flush", epoch=new_epoch, staged=staged,
                             inserts=len(inserts), deletes=len(deletes)) as sp:
+                if not self._flush_warmed:
+                    self._warm_flush()
+                    self._flush_warmed = True
                 purged, merged, rounds, f_rounds = self._apply_delta(
                     deletes, inserts, new_epoch
                 )
@@ -1119,6 +1225,11 @@ class EngineCore:
             )
         return result
 
+    def _warm_flush(self) -> None:
+        """Compile every program a flush of this engine can dispatch (the
+        first flush calls it, before its work). The sharded engine lists no
+        set (module docstring), so nothing here."""
+
     def _flush_guard(self):
         """Sanitizer rail: the device flush pipeline runs under the transfer
         guard (all uploads must be explicit device_puts); the "host"
@@ -1140,61 +1251,77 @@ class EngineCore:
             self._stats["flushes_failed"] += 1
             raise
 
+    def _apply_phases(
+        self, deletes: list[int], inserts: list[int]
+    ) -> tuple[int, int, int, int]:
+        """One delta's phases on the working tables. Returns (rows purged,
+        rows merged, repair rounds, frontier rounds)."""
+        # -- delete side: which rows name a deleted object (device scan) --
+        purged_rows = np.empty(0, np.int32)
+        if deletes:
+            with self._span("flush.scan"):
+                purged_rows = self._scan_delete_rows(deletes)
+
+        # -- insert side: batched checkIns frontier, insert-first semantics --
+        # The frontier prunes against the CURRENT (pre-update) k-th bounds,
+        # exactly Algorithm 4 run before Algorithm 5 (the same order the
+        # scalar ``move_object`` oracle uses). A row the pruning misses that
+        # still needs a new object in the *final* tables must have had its
+        # k-th distance raised by the deletions — i.e. it lost an entry, so
+        # it is in the purge set and the repair rounds rebuild it from its
+        # bridge neighbors anyway. Keeping the pre-update bounds keeps the
+        # frontier as tight as the oracle's, instead of the unpruned sweep a
+        # post-purge (unbounded) k-th would trigger.
+        f_rounds = 0
+        frows = np.empty(0, np.int32)
+        fc_ids = fc_d = None
+        if inserts:
+            provider = (
+                self._insert_frontier_host
+                if self.frontier == "host"
+                else self._insert_frontier
+            )
+            with self._span("flush.frontier"):
+                frows, fc_ids, fc_d, f_rounds = provider(inserts)
+
+        # -- one fused purge + merge over the union of both row sets --
+        rounds = 0
+        if purged_rows.size or frows.size:
+            with self._span("flush.purge_merge"):
+                rows = np.union1d(purged_rows, frows).astype(np.int32)
+                p = fc_ids.shape[1] if frows.size else self._cand_width(1)
+                cand_ids = np.full((len(rows), p), -1, np.int32)
+                cand_d = np.full((len(rows), p), np.inf, np.float32)
+                if frows.size:
+                    pos = np.searchsorted(rows, frows)
+                    cand_ids[pos] = fc_ids
+                    cand_d[pos] = fc_d
+                self._purge_merge(rows, deletes, cand_ids, cand_d)
+            # -- breadth-first repair of the deletion holes (shared frontier) --
+            if purged_rows.size:
+                with self._span("flush.repair"):
+                    rounds = self._repair(purged_rows)
+        return int(purged_rows.size), int(frows.size), rounds, f_rounds
+
     def _apply_delta(
         self, deletes: list[int], inserts: list[int], new_epoch: int
     ) -> tuple[int, int, int, int]:
-        """The flush's phases, then the publish of ``new_epoch``. Returns
-        (rows purged, rows merged, repair rounds, frontier rounds)."""
+        """The flush's phases, once per delta of at most the delta cap, then
+        the publish of ``new_epoch``. Returns (rows purged, rows merged,
+        repair rounds, frontier rounds), summed over the deltas."""
         # Epoch e+1 is built on the working references; the published epoch
         # e snapshot keeps its own references to the old buffers, so queries
         # dispatched anywhere in here still read a whole epoch.
         base = self._epochs.snapshot()
+        size = max(len(deletes), len(inserts))
+        cap = self._delta_cap()
+        totals = np.zeros(4, np.int64)
         with self._rollback_on_failure(base), self._flush_guard():
-            # -- delete side: which rows name a deleted object (device scan) --
-            purged_rows = np.empty(0, np.int32)
-            if deletes:
-                with self._span("flush.scan"):
-                    purged_rows = self._scan_delete_rows(deletes)
-
-            # -- insert side: batched checkIns frontier, insert-first semantics --
-            # The frontier prunes against the CURRENT (pre-update) k-th bounds,
-            # exactly Algorithm 4 run before Algorithm 5 (the same order the
-            # scalar ``move_object`` oracle uses). A row the pruning misses that
-            # still needs a new object in the *final* tables must have had its
-            # k-th distance raised by the deletions — i.e. it lost an entry, so
-            # it is in the purge set and the repair rounds rebuild it from its
-            # bridge neighbors anyway. Keeping the pre-update bounds keeps the
-            # frontier as tight as the oracle's, instead of the unpruned sweep a
-            # post-purge (unbounded) k-th would trigger.
-            f_rounds = 0
-            frows = np.empty(0, np.int32)
-            fc_ids = fc_d = None
-            if inserts:
-                provider = (
-                    self._insert_frontier_host
-                    if self.frontier == "host"
-                    else self._insert_frontier
-                )
-                with self._span("flush.frontier"):
-                    frows, fc_ids, fc_d, f_rounds = provider(inserts)
-
-            # -- one fused purge + merge over the union of both row sets --
-            rounds = 0
-            if purged_rows.size or frows.size:
-                with self._span("flush.purge_merge"):
-                    rows = np.union1d(purged_rows, frows).astype(np.int32)
-                    p = fc_ids.shape[1] if frows.size else 1
-                    cand_ids = np.full((len(rows), p), -1, np.int32)
-                    cand_d = np.full((len(rows), p), np.inf, np.float32)
-                    if frows.size:
-                        pos = np.searchsorted(rows, frows)
-                        cand_ids[pos] = fc_ids
-                        cand_d[pos] = fc_d
-                    self._purge_merge(rows, deletes, cand_ids, cand_d)
-                # -- breadth-first repair of the deletion holes (shared frontier) --
-                if purged_rows.size:
-                    with self._span("flush.repair"):
-                        rounds = self._repair(purged_rows)
+            # each delta is a valid object-set change (the net deletes and
+            # inserts are disjoint), and the tables after it are exact for
+            # its object set, so the last one's are the flush's
+            for i in range(0, size, cap):
+                totals += self._apply_phases(deletes[i:i + cap], inserts[i:i + cap])
 
         # -- staged layout changes (repartition-on-flush) ride the same
         # epoch: the hook re-lays the working tables so the swap below
@@ -1210,7 +1337,7 @@ class EngineCore:
             self._publish_epoch(new_epoch)
             if self._journal is not None:
                 self._journal.commit(new_epoch)
-        return int(purged_rows.size), int(frows.size), rounds, f_rounds
+        return tuple(int(x) for x in totals)
 
     # ------------------------------------------------------------------
     # persistence / stats
@@ -1357,6 +1484,8 @@ class QueryEngine(EngineCore):
         use_pallas: bool = False,
     ):
         self.n, self._vk_ids, self._vk_d = self.normalize_tables(ids, dists, k, bn)
+        # every flush signature compiled or dispatched (``_flush_signatures``)
+        self._flush_programs: set[tuple] = set()
         super().__init__(k, objects, bn=bn, use_pallas=use_pallas)
 
     # ------------------------------------------------------------------
@@ -1417,8 +1546,82 @@ class QueryEngine(EngineCore):
     def _gather_batch(self, us: np.ndarray, ks: jax.Array, snap: tuple, epoch: int):
         return ops.serve_gather(snap[0], snap[1], jax.device_put(us), ks)
 
+    # the closed flush program set --------------------------------------
+
+    def _flush_signatures(self) -> list[tuple]:
+        """Every (program, shape) a flush of this engine can dispatch, from
+        the tier helpers the flush pads with: the padded deletes are the
+        delta cap, the sources B each of ``_src_widths``; the candidate
+        widths are ``_cand_width``'s up to the cap; rows are
+        ``_rows_width``'s up to n, and for a round's part at width t up to
+        the vertices it can hold (``_t_widths``: a part holds each vertex
+        once)."""
+        self._nbr_tables()
+        srcs = self._src_widths()
+        sigs = [("rows_containing", self._delta_cap())]
+        sigs += [("frontier_init", b) for b in srcs]
+        for t, rows in self._t_widths():
+            for r in _tiers(self._rows_width, rows):
+                sigs += [("frontier_round", t, r, b) for b in srcs]
+                sigs.append(("repair_round", t, r))
+        for r in _tiers(self._rows_width, self.n):
+            sigs += [("frontier_affected", r, b) for b in srcs]
+            sigs += [("rows_purge_merge", r, p)
+                     for p in _tiers(self._cand_width, self._delta_cap())]
+        return sigs
+
+    def _lower(self, sig: tuple):
+        """The program of ``sig`` lowered on the shapes a flush calls it
+        with, argument for argument as the dispatch passes them."""
+        name, *dims = sig
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+        n1 = self.n + 1
+        ids, d = i32(n1, self.k), f32(n1, self.k)
+        if name == "rows_containing":
+            return ops.rows_containing.lower(ids, i32(*dims))
+        if name == "frontier_init":
+            return _frontier_init_prog.lower(i32(*dims), n1)
+        if name == "frontier_affected":
+            r, b = dims
+            return _frontier_affected.lower(i32(r), f32(n1, b), d, i32(b))
+        if name == "rows_purge_merge":
+            r, p = dims
+            return ops.rows_purge_merge.lower(
+                ids, d, i32(r), i32(self._delta_cap()), i32(r, p), f32(r, p), self.k,
+                use_pallas=self.use_pallas, sorted_rows=True)
+        t, r, *b = dims
+        nbr, w = i32(self._nbr_ids.shape[0], t), f32(self._nbr_ids.shape[0], t)
+        if name == "frontier_round":
+            return _frontier_round.lower(nbr, w, i32(r), f32(n1, *b), d, i32(*b),
+                                         self.use_pallas)
+        return _repair_round.lower(nbr, w, i32(r), ids, d)
+
+    def _warm_flush(self) -> None:
+        """Compile every flush signature (``_flush_signatures``), the
+        backend compiles in parallel: lowering holds the interpreter, the
+        compile does not. Runs nothing and touches no table. A second
+        engine of the same shapes finds them in jax's own caches."""
+        if self.bn is None:
+            return
+        sigs = self._flush_signatures()
+        with self._span("flush.warm", programs=len(sigs)):
+            lowered = [self._lower(sig) for sig in sigs]
+            with ThreadPoolExecutor(os.cpu_count()) as pool:
+                list(pool.map(lambda low: low.compile(), lowered))
+        self._flush_programs.update(sigs)
+
+    def _dispatched(self, *sig) -> None:
+        self._flush_programs.add(sig)
+
+    def _extra_stats(self) -> dict:
+        # distinct flush signatures compiled or dispatched so far: after the
+        # first flush, len(_flush_signatures()), and it never grows
+        return {"flush_programs": len(self._flush_programs)}
+
     def _scan_delete_rows(self, deletes: list[int]) -> np.ndarray:
         del_arr = self._upload(self._padded_deletes(deletes))
+        self._dispatched("rows_containing", del_arr.shape[0])
         hit = self._readback(ops.rows_containing(self._vk_ids, del_arr))
         return np.flatnonzero(hit).astype(np.int32)
 
@@ -1426,21 +1629,24 @@ class QueryEngine(EngineCore):
         return self._readback(self._vk_d[: self.n, -1]).astype(np.float64)
 
     def _purge_merge(self, rows, deletes, cand_ids, cand_d) -> None:
-        r_pad = _pow2_pad(len(rows), lo=64)  # must match _pad_rows
+        r_pad = self._rows_width(len(rows))
         pad = ((0, r_pad - len(rows)), (0, 0))
         cand_ids = np.pad(cand_ids, pad, constant_values=-1)
         cand_d = np.pad(cand_d, pad, constant_values=np.inf)
+        self._dispatched("rows_purge_merge", r_pad, cand_ids.shape[1])
         self._vk_ids, self._vk_d = ops.rows_purge_merge(
             self._vk_ids, self._vk_d, self._pad_rows(rows),
             self._upload(self._padded_deletes(deletes)),
             self._upload(cand_ids), self._upload(cand_d), self.k,
-            use_pallas=self.use_pallas,
+            use_pallas=self.use_pallas, sorted_rows=True,
         )
 
     def _repair_part(self, part: np.ndarray) -> np.ndarray:
         nbr_tab, w_tab = self._nbr_slice(self._t_bucket(part))
+        rows = self._pad_rows(part)
+        self._dispatched("repair_round", nbr_tab.shape[1], rows.shape[0])
         self._vk_ids, self._vk_d, changed_mask = _repair_round(
-            nbr_tab, w_tab, self._pad_rows(part), self._vk_ids, self._vk_d
+            nbr_tab, w_tab, rows, self._vk_ids, self._vk_d
         )
         return self._readback(changed_mask)
 
@@ -1451,18 +1657,23 @@ class QueryEngine(EngineCore):
 
     def _frontier_init(self, src: np.ndarray) -> jax.Array:
         self._fsrc = self._upload(self._frontier_pad_src(src))
+        self._dispatched("frontier_init", self._fsrc.shape[0])
         return _frontier_init_prog(self._fsrc, self._vk_ids.shape[0])
 
     def _frontier_part(self, state, part: np.ndarray):
         nbr_tab, w_tab = self._nbr_slice(self._t_bucket(part))
+        rows = self._pad_rows(part)
+        self._dispatched("frontier_round", nbr_tab.shape[1], rows.shape[0],
+                         self._fsrc.shape[0])
         state, changed = _frontier_round(
-            nbr_tab, w_tab, self._pad_rows(part), state, self._vk_d,
-            self._fsrc, self.use_pallas,
+            nbr_tab, w_tab, rows, state, self._vk_d, self._fsrc, self.use_pallas,
         )
         return state, self._readback(changed)
 
     def _frontier_extract(self, state, rows: np.ndarray, src: np.ndarray):
-        aff, d = _frontier_affected(self._pad_rows(rows), state, self._vk_d, self._fsrc)
+        padded = self._pad_rows(rows)
+        self._dispatched("frontier_affected", padded.shape[0], self._fsrc.shape[0])
+        aff, d = _frontier_affected(padded, state, self._vk_d, self._fsrc)
         b = len(src)
         return self._readback(aff)[: len(rows), :b], self._readback(d)[: len(rows), :b]
 
@@ -1519,11 +1730,13 @@ def _frontier_round(nbr_tab, w_tab, rows, dist, vk_d, src, use_pallas: bool):
     ``ops.frontier_relax`` against the live table's k-th column (device
     resident — sliced inside the program), and derive the changed mask that
     narrows the next round's receiver set. Distances only ever decrease, so
-    ``new < old`` is exactly "changed"."""
+    ``new < old`` is exactly "changed". Every row batch of a flush is
+    ascending (its pads, the dummy row n, last), so the writes say so."""
     nbr = nbr_tab[rows]
     w = w_tab[rows]
     kth = vk_d[:, -1]
-    new = ops.frontier_relax(nbr, rows, w, dist, kth, src, use_pallas=use_pallas)
+    new = ops.frontier_relax(nbr, rows, w, dist, kth, src, use_pallas=use_pallas,
+                             sorted_rows=True)
     changed = jnp.any(new[rows] < dist[rows], axis=1)
     return new, changed
 
@@ -1546,13 +1759,37 @@ def _repair_round(nbr_tab, w_tab, rows, vk_ids, vk_d):
     entries (extras tables = the live tables themselves) with its bridge
     neighbors' rows; returns the per-row changed mask the caller uses to
     narrow the next round's frontier (Jacobi: see the module docstring).
+    The rows ascend, as in ``_frontier_round``.
+
+    One gather of many k-wide table rows takes the TPU's compiler a time
+    that grows with its number of indices (36 s at 131,072 x 8), so past
+    ``_GATHER_INDICES`` the rows merge a block at a time, each block's
+    gather within it; every block reads the pre-round tables.
     """
     k = vk_ids.shape[1]
-    nbr = nbr_tab[rows]
-    w = w_tab[rows]
-    new_ids, new_d = ops.sweep_merge(
-        nbr, rows, w, vk_ids, vk_d, vk_ids, vk_d, k, use_pallas=False
-    )
+    r, t = rows.shape[0], nbr_tab.shape[1]
+
+    def merge(part):
+        return ops.sweep_merge_rows(nbr_tab[part], part, w_tab[part], vk_ids, vk_d,
+                                    vk_ids, vk_d, k, use_pallas=False)
+
+    rb = max(8, _GATHER_INDICES // t // 8 * 8)
+    if r <= rb:
+        m_ids, m_d = merge(rows)
+    else:
+        def block(j, acc):
+            # the last block ends at row r, overlapping the one before it:
+            # its rows are merged twice, to the same values
+            start = jnp.minimum(j * rb, r - rb)
+            got = merge(jax.lax.dynamic_slice_in_dim(rows, start, rb))
+            return tuple(jax.lax.dynamic_update_slice_in_dim(a, g, start, axis=0)
+                         for a, g in zip(acc, got))
+
+        m_ids, m_d = jax.lax.fori_loop(
+            0, -(-r // rb), block,
+            (jnp.zeros((r, k), vk_ids.dtype), jnp.zeros((r, k), vk_d.dtype)))
+    new_ids = vk_ids.at[rows].set(m_ids, indices_are_sorted=True)
+    new_d = vk_d.at[rows].set(m_d, indices_are_sorted=True)
     changed = jnp.any(
         (new_ids[rows] != vk_ids[rows]) | (new_d[rows] != vk_d[rows]), axis=1
     )

@@ -20,13 +20,19 @@ call:
 ``knn:flush``                ``EngineCore.flush_updates`` to the publish; attrs
                              ``epoch`` (the one it publishes), ``staged``,
                              ``inserts``, ``deletes`` (the net delta)
+``knn:flush.warm``           an engine's first flush only: compiling every
+                             program a flush can dispatch; attr ``programs``
 ``knn:flush.scan``           the delete-hit row scan
 ``knn:flush.frontier``       the insert frontier (device rounds or host)
 ``knn:flush.frontier.round`` one frontier round; attrs ``round``, ``rows``
+``knn:flush.frontier.part``  one program dispatch of a frontier round; attrs
+                             ``rows`` (unpadded), ``t`` (the width bucket)
 ``knn:flush.purge_merge``    host side of the purge-merge: candidates,
                              padding, uploads, enqueue
 ``knn:flush.repair``         the repair rounds
 ``knn:flush.repair.round``   one repair round; attrs ``round``, ``rows``
+``knn:flush.repair.part``    one program dispatch of a repair round; attrs
+                             ``rows`` (unpadded), ``t`` (the width bucket)
 ``knn:flush.readback``       each blocking device->host readback of a flush;
                              attr ``bytes``
 ``knn:flush.publish``        layout hook, epoch swap, journal commit

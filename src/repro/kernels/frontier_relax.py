@@ -105,8 +105,10 @@ def frontier_relax_pallas(
     src: jax.Array,   # (B,) int32 source vertex per column, -1 pad
     *,
     interpret: bool = False,
+    sorted_rows: bool = False,
 ) -> jax.Array:
-    """One fused frontier round; returns the updated (n+1, B) dist matrix."""
+    """One fused frontier round; returns the updated (n+1, B) dist matrix.
+    ``sorted_rows`` as ``ops.frontier_relax``."""
     r, t = nbr.shape
     n1 = dist.shape[0]
     padded = -(-r // ROW_BLOCK) * ROW_BLOCK
@@ -121,4 +123,4 @@ def frontier_relax_pallas(
         )
         for s, m in row_slices(padded, 3 * t + 1)
     ])
-    return dist.at[rows].set(new[:r])
+    return dist.at[rows].set(new[:r], indices_are_sorted=sorted_rows)

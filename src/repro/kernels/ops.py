@@ -203,6 +203,7 @@ def frontier_relax(
     *,
     use_pallas: bool = True,
     interpret: bool | None = None,
+    sorted_rows: bool = False,
 ) -> jax.Array:
     """One batched pruned-relaxation round of the checkIns frontier.
 
@@ -221,6 +222,10 @@ def frontier_relax(
     materialise; the Pallas kernel fuses the gather/gate/min per neighbor
     row (see kernels/frontier_relax.py). Both are pure Jacobi: every
     neighbor read sees the pre-round ``dist``.
+
+    ``sorted_rows`` promises ``rows`` ascending (pads, the largest id, last),
+    which the write back passes on to the scatter: the TPU's compiler then
+    compiles it in a time that does not grow with R.
     """
     if not use_pallas:
         n1 = dist.shape[0]
@@ -237,9 +242,10 @@ def frontier_relax(
             return jnp.minimum(acc, jnp.where(ok, cand, jnp.inf))
 
         acc = jax.lax.fori_loop(0, nbr.shape[1], body, dist[rows])
-        return dist.at[rows].set(acc)
+        return dist.at[rows].set(acc, indices_are_sorted=sorted_rows)
     itp = (not _on_tpu()) if interpret is None else interpret
-    return frontier_relax_pallas(nbr, rows, w, dist, kth, src, interpret=itp)
+    return frontier_relax_pallas(nbr, rows, w, dist, kth, src, interpret=itp,
+                                 sorted_rows=sorted_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
@@ -269,7 +275,7 @@ def rows_merge(
     return vk_ids.at[rows].set(m_ids), vk_d.at[rows].set(m_d)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret", "sorted_rows"))
 def rows_purge_merge(
     vk_ids: jax.Array,    # (n+1, k) int32 live table
     vk_d: jax.Array,      # (n+1, k) float32
@@ -281,6 +287,7 @@ def rows_purge_merge(
     *,
     use_pallas: bool = True,
     interpret: bool | None = None,
+    sorted_rows: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused batched move repair: purge + candidate merge in ONE pass.
 
@@ -291,7 +298,8 @@ def rows_purge_merge(
     gather/merge/scatter followed by a separate insert gather/merge/scatter
     over largely the same rows. ``rows`` is the union of the delete-hit rows
     and the insert (checkIns) frontier; rows outside one of the two sets just
-    carry all-pad columns for the other.
+    carry all-pad columns for the other. ``sorted_rows`` as in
+    ``frontier_relax``.
     """
     own_ids = vk_ids[rows]
     own_d = vk_d[rows]
@@ -302,7 +310,8 @@ def rows_purge_merge(
     cat_d = jnp.concatenate([pd, cand_d.astype(vk_d.dtype)], axis=1)
     cat_d = jnp.where(cat_ids < 0, jnp.inf, cat_d)
     m_ids, m_d = topk_merge(cat_ids, cat_d, k, use_pallas=use_pallas, interpret=interpret)
-    return vk_ids.at[rows].set(m_ids), vk_d.at[rows].set(m_d)
+    return (vk_ids.at[rows].set(m_ids, indices_are_sorted=sorted_rows),
+            vk_d.at[rows].set(m_d, indices_are_sorted=sorted_rows))
 
 
 # ----------------------------------------------------------------------

@@ -269,3 +269,45 @@ def test_spans_land_in_a_profiler_trace(tmp_path, sanitize_off):
     sweeps = by["knn:build.sweep"]
     assert [a["direction"] for _, _, a in sweeps] == ["up", "down"]
     assert all(bs <= s and e <= be for s, e, _ in sweeps + by["knn:build.extras"])
+
+
+def test_the_first_flush_warms_and_each_round_spans_its_parts(tmp_path, sanitize_off):
+    """``knn:flush.warm`` runs in the first flush only, with the number of
+    programs it compiled; every program dispatch of a round is a
+    ``.part`` span inside it, with its unpadded rows and width bucket."""
+    from jax.profiler import ProfileData
+
+    g, objects, bn, idx = _setup(grid=11)
+    eng = knn.QueryEngine.from_index(idx, objects, bn=bn)
+    rng = np.random.default_rng(7)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            _stage_churn(eng, g, rng)
+            eng.flush_updates()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    by = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("knn:"):
+                    by.setdefault(ev.name.split("#", 1)[0], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    first, second = sorted(by["knn:flush"])
+    (ws, we, wattrs), = by["knn:flush.warm"]
+    assert first[0] <= ws and we <= first[1]
+    assert wattrs["programs"] == len(eng._flush_signatures()) == eng.stats()["flush_programs"]
+    tiers = eng._t_tiers()
+    for kind in ("frontier", "repair"):
+        rounds = by[f"knn:flush.{kind}.round"]
+        parts = by[f"knn:flush.{kind}.part"]
+        assert parts and {a["t"] for _, _, a in parts} <= set(tiers)
+        assert all(a["rows"] >= 1 for _, _, a in parts)
+        for s, e, a in rounds:
+            inner = [p for p in parts if s <= p[0] and p[1] <= e]
+            assert inner
+            if kind == "repair":  # a repair round's parts split its rows
+                assert sum(p[2]["rows"] for p in inner) == a["rows"]
+        assert all(any(s <= p[0] and p[1] <= e for s, e, _ in rounds) for p in parts)
