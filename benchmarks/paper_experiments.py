@@ -305,8 +305,6 @@ def exp11_engine_serving() -> None:
     (batch size, queries/s, staged-queue depth) as meta for the CI schema
     check; the engine batch path must report >= 10x the scalar loop's ops/s.
     """
-    import jax
-
     from repro import knn
 
     k = 20
@@ -331,12 +329,11 @@ def exp11_engine_serving() -> None:
     best_qps, best_b = 0.0, 0
     for b in (512, 4096):
         us = rng.integers(0, g.n, size=b)
-        jax.block_until_ready(engine.query_batch(us)[0])  # compile outside timing
+        engine.query_batch(us)  # compile outside timing
         t0 = time.perf_counter()
         n = 0
         while time.perf_counter() - t0 < 1.0:
-            ids, _ = engine.query_batch(us)
-            jax.block_until_ready(ids)
+            engine.query_batch(us)
             n += b
         qps = n / (time.perf_counter() - t0)
         if qps > best_qps:
@@ -347,13 +344,12 @@ def exp11_engine_serving() -> None:
     # mixed traffic: query tiles + staged updates flushed per tile (BUA)
     mset = set(engine.objects.tolist())
     batch, n_upd = 512, 26
-    jax.block_until_ready(engine.query_batch(rng.integers(0, g.n, size=batch))[0])
+    engine.query_batch(rng.integers(0, g.n, size=batch))
     depth = 0
     t0 = time.perf_counter()
     ops_done = 0
     for _ in range(6):
-        ids, _ = engine.query_batch(rng.integers(0, g.n, size=batch))
-        jax.block_until_ready(ids)
+        engine.query_batch(rng.integers(0, g.n, size=batch))
         staged = knn.stage_random_updates(engine, mset, rng, n_upd)
         depth = max(depth, engine.queue_depth)
         engine.flush_updates()
@@ -370,10 +366,9 @@ def exp11_engine_serving() -> None:
     from repro.analysis import sanitize
 
     us = rng.integers(0, g.n, size=best_b)
-    jax.block_until_ready(engine.query_batch(us)[0])
+    engine.query_batch(us)
     with sanitize.count_compiles() as cc, sanitize.count_transfers() as tc:
-        ids, _ = engine.query_batch(us)
-        jax.block_until_ready(ids)
+        engine.query_batch(us)
     row("exp11.serve.engine_query_batch.warm_counters", 0.0,
         f"c{cc.count};h2d{tc.h2d};d2h{tc.d2h}")
 
@@ -483,14 +478,13 @@ def exp13_sharded_scaling() -> None:
     def measure_queries(engine) -> float:
         # best of 3 windows: the parity floor divides two of these numbers,
         # so single-window scheduler noise would flap the acceptance check
-        jax.block_until_ready(engine.query_batch(us)[0])  # compile off-clock
+        engine.query_batch(us)  # compile off-clock
         best = 0.0
         for _ in range(3):
             t0 = time.perf_counter()
             served = 0
             while time.perf_counter() - t0 < 0.3:
-                ids, _ = engine.query_batch(us)
-                jax.block_until_ready(ids)
+                engine.query_batch(us)
                 served += batch
             best = max(best, served / (time.perf_counter() - t0))
         return best
@@ -811,14 +805,13 @@ def exp16_hot_shard() -> None:
     def measure() -> float:
         # best of 3 windows, compile off-clock (same shape as exp13: the
         # floor divides two of these, so one noisy window may not flap it)
-        jax.block_until_ready(engine.query_batch(us)[0])
+        engine.query_batch(us)
         best = 0.0
         for _ in range(3):
             t0 = time.perf_counter()
             served = 0
             while time.perf_counter() - t0 < 0.3:
-                ids, _ = engine.query_batch(us)
-                jax.block_until_ready(ids)
+                engine.query_batch(us)
                 served += batch
             best = max(best, served / (time.perf_counter() - t0))
         return best
@@ -907,14 +900,13 @@ def exp17_uneven_ranges() -> None:
 
     def measure() -> float:
         # best of 3 windows, compile off-clock (same shape as exp16)
-        jax.block_until_ready(engine.query_batch(us)[0])
+        engine.query_batch(us)
         best = 0.0
         for _ in range(3):
             t0 = time.perf_counter()
             served = 0
             while time.perf_counter() - t0 < 0.3:
-                ids, _ = engine.query_batch(us)
-                jax.block_until_ready(ids)
+                engine.query_batch(us)
                 served += batch
             best = max(best, served / (time.perf_counter() - t0))
         return best

@@ -26,7 +26,6 @@ Prints the throughputs and speedups; the engine paths are also what
 import argparse
 import time
 
-import jax
 import numpy as np
 
 from repro import knn
@@ -70,7 +69,7 @@ def run_engine_batched(engine, n_ops: int, update_frac: float,
 
     def one_tile():
         us = rng.integers(0, engine.n, size=n_q)
-        jax.block_until_ready(engine.query_batch(us)[0])
+        engine.query_batch(us)
         if knn.stage_random_updates(engine, mset, rng, n_upd):
             engine.flush_updates()
 
@@ -79,8 +78,7 @@ def run_engine_batched(engine, n_ops: int, update_frac: float,
     t_q = t_u = 0.0
     while ops_done < n_ops:
         t0 = time.perf_counter()
-        ids, _ = engine.query_batch(rng.integers(0, engine.n, size=n_q))
-        jax.block_until_ready(ids)
+        engine.query_batch(rng.integers(0, engine.n, size=n_q))  # read back on return
         t_q += time.perf_counter() - t0
         queries += n_q
         t0 = time.perf_counter()
@@ -105,7 +103,7 @@ def run_fleet(g, bn, k: int, fleet_size: int, ticks: int, batch: int,
     sim = knn.FleetSim(g, fleet_size=fleet_size, seed=seed)
     engine = knn.build_engine(bn, sim.positions, k)
     rng = np.random.default_rng(seed)
-    jax.block_until_ready(engine.query_batch(rng.integers(0, g.n, size=batch))[0])
+    engine.query_batch(rng.integers(0, g.n, size=batch))  # compile the gather
     r = drive_fleet_ticks(
         engine, (sim.tick() for _ in range(ticks)), batch=batch, rng=rng
     )

@@ -101,8 +101,10 @@ round's receiver set) and, once the frontier converges, the affected rows'
 distance tiles come back. The (n,) k-th-distance column — the checkIns
 pruning bound — never leaves the device: the frontier rounds read it
 straight off the live distance table, so per-flush readback is proportional
-to the affected set, not to n. Queries move only the query ids up and the
-(B, k) result tiles back. Every readback of a flush goes through
+to the affected set, not to n. Queries move only the query ids and ks up
+and one packed (B, 2k) answer buffer back (``ops.pack_answer``: a batch's
+one readback, the ``knn:query.readback`` span, ``stats()["query_readbacks"]``
+and ``["query_readback_bytes"]``). Every readback of a flush goes through
 ``EngineCore._readback`` and every upload through ``_upload``, which count
 them (``stats()["flush_readbacks"]`` ..., per flush in ``epoch_stats(e)``);
 each readback is a ``knn:flush.readback`` span, the host waiting on the
@@ -291,10 +293,11 @@ class EngineCore:
     Subclasses own the table storage and implement the device hooks:
 
     * ``_gather_batch(us, ks, snap, epoch)`` — the batched row gather
-      behind ``query_batch`` (full index-k width; the core applies stats
-      and the per-query width slice). ``snap`` is the epoch snapshot
-      resolved at dispatch and ``epoch`` its number — the gather must read
-      the snapshot, never the working tables, so queries stay
+      behind ``query_batch``, ending in the packed answer buffer at full
+      index-k width (``ops.pack_answer``; the core reads it back once and
+      applies stats and the per-query width slice). ``snap`` is the epoch
+      snapshot resolved at dispatch and ``epoch`` its number — the gather
+      must read the snapshot, never the working tables, so queries stay
       snapshot-isolated from an in-flight flush; the epoch number lets a
       subclass key per-epoch serving state (replica buffers) consistently.
     * ``_table_snapshot()`` — the current working tables as an immutable
@@ -347,6 +350,8 @@ class EngineCore:
         self._stats = {
             "queries_served": 0,
             "query_batches": 0,
+            "query_readbacks": 0,
+            "query_readback_bytes": 0,
             "last_batch_size": 0,
             "flushes": 0,
             "flushes_failed": 0,
@@ -608,15 +613,17 @@ class EngineCore:
 
     def _gather_batch(self, us: np.ndarray, ks: jax.Array, snap: tuple, epoch: int):
         """Batched row gather at full index-k width against the ``snap``
-        epoch snapshot (never the working tables — see the class doc);
-        ``us`` is a host array so a sharded engine can route queries by
-        owner before the device roundtrip. ``epoch`` is the resolved epoch
+        epoch snapshot (never the working tables — see the class doc),
+        ending in the packed answer buffer (``ops.pack_answer``); ``us``
+        is a host array so a sharded engine can route queries by owner
+        before the device roundtrip. ``epoch`` is the resolved epoch
         number of ``snap`` (for subclasses with epoch-keyed serving state,
         e.g. replica buffers behind the routing table)."""
         raise NotImplementedError
 
-    def query_batch(self, us, k=None, *, epoch=None) -> tuple[jax.Array, jax.Array]:
-        """Batched kNN: (B,) vertices -> ((B, k') ids, (B, k') dists).
+    def query_batch(self, us, k=None, *, epoch=None) -> tuple[np.ndarray, np.ndarray]:
+        """Batched kNN: (B,) vertices -> ((B, k') int32 ids, (B, k') float32
+        dists), host arrays read back in one transfer.
 
         ``k`` may be None (index k), a scalar, or a (B,) array for mixed-k
         traffic; columns past a query's k hold the pad sentinel (-1, +inf).
@@ -637,17 +644,21 @@ class EngineCore:
                 with self._span("query.ks"):
                     ks, width = self._ks_array(b, k)
                 with self._span("query.gather"):
-                    ids, d = self._gather_batch(us, ks, snap, epoch_r)
+                    packed = self._gather_batch(us, ks, snap, epoch_r)
+            packed.copy_to_host_async()  # the copy overlaps the bookkeeping
             self._stats["queries_served"] += b
             self._stats["query_batches"] += 1
             self._stats["last_batch_size"] = b
-            if width < self.k:
-                ids, d = ids[:, :width], d[:, :width]
-        return ids, d
+        # the answer's one readback: the host waiting on the device
+        with self._span("query.readback", bytes=int(packed.nbytes)):
+            buf = np.asarray(packed)
+        self._stats["query_readbacks"] += 1
+        self._stats["query_readback_bytes"] += buf.nbytes
+        return ops.unpack_answer(buf, self.k, width)
 
     def query_progressive_batch(
         self, us, k=None, *, epoch=None
-    ) -> Iterator[tuple[jax.Array, jax.Array]]:
+    ) -> Iterator[tuple[np.ndarray, np.ndarray]]:
         """Progressive batched output: yields the first-i prefix for
         i = 1..k from ONE gather — O(i) work to surface i results per query
         (Theorem 4.4, batched)."""
@@ -1544,7 +1555,8 @@ class QueryEngine(EngineCore):
         self._vk_ids, self._vk_d = snap
 
     def _gather_batch(self, us: np.ndarray, ks: jax.Array, snap: tuple, epoch: int):
-        return ops.serve_gather(snap[0], snap[1], jax.device_put(us), ks)
+        gather = ops.answer_program(ops.serve_gather)
+        return gather(snap[0], snap[1], jax.device_put(us), ks)
 
     # the closed flush program set --------------------------------------
 

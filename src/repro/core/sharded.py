@@ -589,6 +589,13 @@ def _device_fns(mesh: Mesh, block: int, k: int) -> dict:  # replint: disable=REP
 
     spec2 = P("shard", None)
 
+    def gather_epi(gi, gd, fidx, ks):
+        """The gathers' epilogue: the per-shard tiles back in batch order,
+        masked per query and packed (``ops.pack_answer``)."""
+        gi = gi.reshape(-1, k)[fidx]
+        gd = gd.reshape(-1, k)[fidx]
+        return ops.pack_answer(*ops.mask_answer(gi, gd, ks))
+
     def gather(ids_g, d_g, qglob, fidx, ks):
         def blk(ti, td, q):
             off = jax.lax.axis_index("shard") * block
@@ -600,10 +607,7 @@ def _device_fns(mesh: Mesh, block: int, k: int) -> dict:  # replint: disable=REP
             in_specs=(spec2, spec2, spec2),
             out_specs=(P("shard", None, None), P("shard", None, None)),
         )(ids_g, d_g, qglob)
-        gi = gi.reshape(-1, k)[fidx]
-        gd = gd.reshape(-1, k)[fidx]
-        mask = jax.lax.broadcasted_iota(jnp.int32, gi.shape, 1) < ks[:, None]
-        return jnp.where(mask, gi, -1), jnp.where(mask & (gi >= 0), gd, jnp.inf)
+        return gather_epi(gi, gd, fidx, ks)
 
     def scan(ids_g, del_arr):
         def blk(ti, dl):
@@ -869,12 +873,6 @@ def _device_fns(mesh: Mesh, block: int, k: int) -> dict:  # replint: disable=REP
             in_specs=(spec2, spec2, spec2),
             out_specs=(P("shard", None, None), P("shard", None, None)),
         )(ids_g, d_g, qglob)
-
-    def gather_epi(gi, gd, fidx, ks):
-        gi = gi.reshape(-1, k)[fidx]
-        gd = gd.reshape(-1, k)[fidx]
-        mask = jax.lax.broadcasted_iota(jnp.int32, gi.shape, 1) < ks[:, None]
-        return jnp.where(mask, gi, -1), jnp.where(mask & (gi >= 0), gd, jnp.inf)
 
     _DEVICE_FN_CACHE[key] = {
         "gather": jax.jit(gather),
@@ -1571,7 +1569,8 @@ class ShardedQueryEngine(EngineCore):
             # one shard: the global layout IS the scalar (n+1, k) layout and
             # routing is the identity, so serve through the scalar gather
             # (same jitted program the plain engine runs — 1-shard parity)
-            return ops.serve_gather(ids_g, d_g, jnp.asarray(us), ks)
+            gather = ops.answer_program(ops.serve_gather)
+            return gather(ids_g, d_g, jnp.asarray(us), ks)
         qglob, fidx = self._route(us, layout)
         fns = _device_fns(self.mesh, layout.block, self.k)
         if len(us) >= 4096 and qglob.size <= 2 * len(us):
@@ -1611,10 +1610,11 @@ class ShardedQueryEngine(EngineCore):
         vs_p[:m] = vs
         qglob, fidx = self._route(vs_p)
         ks = self._put_repl(np.full((m_pad,), self.k, np.int32))
-        gi, gd = self._gather_fn(
+        packed = self._gather_fn(
             self._ids_g, self._d_g, self._put_shard(qglob), self._put_repl(fidx), ks
         )
-        return self._readback(gi)[:m], self._readback(gd)[:m]
+        ids, d = ops.unpack_answer(self._readback(packed), self.k, self.k)
+        return ids[:m], d[:m]
 
     # ------------------------------------------------------------------
     # flush hooks (per-shard application)

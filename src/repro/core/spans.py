@@ -17,6 +17,8 @@ call:
                              (``query_batches`` before this one), ``epoch``, ``b``
 ``knn:query.ks``             the per-query k upload
 ``knn:query.gather``         the gather hook: routing, uploads, dispatch
+``knn:query.readback``       after ``knn:query``, its sibling: the answer's one
+                             device->host readback; attr ``bytes``
 ``knn:flush``                ``EngineCore.flush_updates`` to the publish; attrs
                              ``epoch`` (the one it publishes), ``staged``,
                              ``inserts``, ``deletes`` (the net delta)

@@ -8,7 +8,7 @@ One import surface for the whole pipeline — build, serve, maintain, persist:
     objects = knn.pick_objects(g.n, 0.02, seed=0)
     engine = knn.build_engine(g, objects, k=20)        # device sweeps end to end
 
-    ids, dists = engine.query_batch(us)                # batched O(k) serving
+    ids, dists = engine.query_batch(us)                # (B, k) numpy, one readback
     engine.stage_insert(u); engine.stage_delete(v)
     engine.stage_move(a, b)                            # moving-objects traffic
     engine.flush_updates()                             # one fused batch repair

@@ -198,7 +198,7 @@ def serve_knn_fleet(args, g, bn, k: int, batch: int, t_bn: float, plan=None) -> 
 
     rng = np.random.default_rng(args.seed + 1)
     # warmup: compile the gather once outside the timed loop
-    jax.block_until_ready(engine.query_batch(rng.integers(0, g.n, size=batch))[0])
+    engine.query_batch(rng.integers(0, g.n, size=batch))
 
     r = drive_fleet_ticks(
         engine, (sim.tick() for _ in range(args.ticks)), batch=batch, rng=rng
@@ -353,9 +353,7 @@ def serve_knn(args) -> dict:
     rounds = max(1, args.ops // (batch + n_upd_round))
 
     # warmup: compile the gather once outside the timed loop
-    jax.block_until_ready(
-        engine.query_batch(_draw_queries(rng, g.n, batch, hot_range, args.hot_frac))[0]
-    )
+    engine.query_batch(_draw_queries(rng, g.n, batch, hot_range, args.hot_frac))
 
     # A failed flush (device error, corrupted batch, injected fault) must
     # not kill serving: the engine rolls back to the last good epoch with
@@ -379,8 +377,7 @@ def serve_knn(args) -> dict:
             hot_range = _hot_range(engine, flip_to, g.n)
         us = _draw_queries(rng, g.n, batch, hot_range, args.hot_frac)
         t0 = time.perf_counter()
-        ids, dists = engine.query_batch(us)
-        jax.block_until_ready(ids)
+        engine.query_batch(us)  # host arrays: the answer is read back
         t_query += time.perf_counter() - t0
         queries += batch
 

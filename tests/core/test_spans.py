@@ -165,7 +165,7 @@ def test_program_names_the_trace_readers_key_on():
     nbr = jnp.zeros((n1, t), jnp.int32)
     w = jnp.zeros((n1, t), jnp.float32)
     dist = jnp.zeros((n1, b), jnp.float32)
-    assert _module_name(ops.serve_gather, ids, d, q, q) == "jit_serve_gather"
+    assert _module_name(ops.answer_program(ops.serve_gather), ids, d, q, q) == "jit_serve_gather"
     assert _module_name(ops.rows_purge_merge, ids, d, rows, q, jnp.zeros((r, 4), jnp.int32),
                         jnp.zeros((r, 4), jnp.float32), k) == "jit_rows_purge_merge"
     assert _module_name(engine_mod._frontier_round, nbr, w, rows, dist, d, q,
@@ -269,6 +269,36 @@ def test_spans_land_in_a_profiler_trace(tmp_path, sanitize_off):
     sweeps = by["knn:build.sweep"]
     assert [a["direction"] for _, _, a in sweeps] == ["up", "down"]
     assert all(bs <= s and e <= be for s, e, _ in sweeps + by["knn:build.extras"])
+
+
+def test_the_query_readback_follows_the_query_span(tmp_path, sanitize_off):
+    """A batch's one readback is ``knn:query.readback``: the sibling after
+    ``knn:query`` (whose seconds hold no readback), with the packed
+    answer's 8 * B * k bytes."""
+    from jax.profiler import ProfileData
+
+    g, objects, bn, idx = _setup()
+    eng = knn.QueryEngine.from_index(idx, objects, bn=bn)
+    us = np.arange(16, dtype=np.int32)
+    eng.query_batch(us, 3)  # compiled outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.query_batch(us, 3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    by = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("knn:query"):
+                    by.setdefault(ev.name.split("#", 1)[0], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    (qs, qe, _), = by["knn:query"]
+    (rs, re, rattrs), = by["knn:query.readback"]
+    assert qe <= rs < re
+    assert rattrs["bytes"] == 8 * len(us) * eng.k
+    assert all(qs <= s and e <= qe for s, e, _ in by["knn:query.gather"])
 
 
 def test_the_first_flush_warms_and_each_round_spans_its_parts(tmp_path, sanitize_off):
