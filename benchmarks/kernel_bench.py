@@ -22,16 +22,6 @@ def bench_topk_merge() -> None:
         row(f"kernel.topk_merge.xla.b{b}c{c}k{k}", t, f"{b * c / t:.0f}cand/us")
 
 
-def bench_retrieval_topk() -> None:
-    rng = np.random.default_rng(0)
-    for b, n, k in [(8, 262144, 100), (1, 1048576, 100)]:
-        s = jnp.asarray(rng.standard_normal((b, n)), jnp.float32)
-        out = ops.retrieval_topk(s, k, use_pallas=False)
-        jax.block_until_ready(out)
-        t = time_us(lambda: jax.block_until_ready(ops.retrieval_topk(s, k, use_pallas=False)))
-        row(f"kernel.retrieval_topk.xla.b{b}n{n}", t, f"{b * n * 4 / t:.0f}B/us")
-
-
 def bench_minplus() -> None:
     rng = np.random.default_rng(0)
     for m in (256, 512):
@@ -43,4 +33,4 @@ def bench_minplus() -> None:
         row(f"kernel.minplus.xla.m{m}", t, f"{2 * m**3 / t / 1e6:.2f}Gop/s")
 
 
-ALL = [bench_topk_merge, bench_retrieval_topk, bench_minplus]
+ALL = [bench_topk_merge, bench_minplus]

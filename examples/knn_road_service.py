@@ -21,7 +21,7 @@ along shortest-path trips, every tick's (src, dst) moves are staged via
 ``stage_move`` and flushed as one fused device batch between query tiles.
 
 Prints the throughputs and speedups; the engine paths are also what
-``repro.launch.serve --arch knn-index [--workload fleet]`` runs as a service.
+``repro.launch.serve [--workload fleet]`` runs as a service.
 """
 import argparse
 import time

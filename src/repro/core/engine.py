@@ -1582,32 +1582,39 @@ class QueryEngine(EngineCore):
                      for p in _tiers(self._cand_width, self._delta_cap())]
         return sigs
 
-    def _lower(self, sig: tuple):
-        """The program of ``sig`` lowered on the shapes a flush calls it
-        with, argument for argument as the dispatch passes them."""
+    def _flush_program(self, sig: tuple):
+        """The program of ``sig`` with the abstract arguments and static
+        keywords a flush calls it with, argument for argument as the
+        dispatch passes them: ``(fn, args, kwargs)``."""
         name, *dims = sig
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
         n1 = self.n + 1
         ids, d = i32(n1, self.k), f32(n1, self.k)
         if name == "rows_containing":
-            return ops.rows_containing.lower(ids, i32(*dims))
+            return ops.rows_containing, (ids, i32(*dims)), {}
         if name == "frontier_init":
-            return _frontier_init_prog.lower(i32(*dims), n1)
+            return _frontier_init_prog, (i32(*dims), n1), {}
         if name == "frontier_affected":
             r, b = dims
-            return _frontier_affected.lower(i32(r), f32(n1, b), d, i32(b))
+            return _frontier_affected, (i32(r), f32(n1, b), d, i32(b)), {}
         if name == "rows_purge_merge":
             r, p = dims
-            return ops.rows_purge_merge.lower(
-                ids, d, i32(r), i32(self._delta_cap()), i32(r, p), f32(r, p), self.k,
-                use_pallas=self.use_pallas, sorted_rows=True)
+            args = (ids, d, i32(r), i32(self._delta_cap()), i32(r, p), f32(r, p), self.k)
+            return ops.rows_purge_merge, args, {"use_pallas": self.use_pallas,
+                                                "sorted_rows": True}
         t, r, *b = dims
         nbr, w = i32(self._nbr_ids.shape[0], t), f32(self._nbr_ids.shape[0], t)
         if name == "frontier_round":
-            return _frontier_round.lower(nbr, w, i32(r), f32(n1, *b), d, i32(*b),
-                                         self.use_pallas)
-        return _repair_round.lower(nbr, w, i32(r), ids, d)
+            return _frontier_round, (nbr, w, i32(r), f32(n1, *b), d, i32(*b),
+                                     self.use_pallas), {}
+        return _repair_round, (nbr, w, i32(r), ids, d), {}
+
+    def _lower(self, sig: tuple):
+        """The program of ``sig`` lowered on the shapes a flush calls it
+        with (``_flush_program``)."""
+        fn, args, kwargs = self._flush_program(sig)
+        return fn.lower(*args, **kwargs)
 
     def _warm_flush(self) -> None:
         """Compile every flush signature (``_flush_signatures``), the

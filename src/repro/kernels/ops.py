@@ -16,10 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.frontier_relax import frontier_relax_pallas
 from repro.kernels.minplus import minplus_matmul_pallas
-from repro.kernels.retrieval_topk import retrieval_topk_pallas
 from repro.kernels.sweep_merge import sweep_merge_pallas
 from repro.kernels.topk_merge import kround_merge, topk_merge_pallas
 
@@ -282,33 +280,6 @@ def frontier_relax(
                                  sorted_rows=sorted_rows)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
-def rows_merge(
-    vk_ids: jax.Array,    # (n+1, k) int32 live table
-    vk_d: jax.Array,      # (n+1, k) float32
-    rows: jax.Array,      # (R,) int32 target rows, n (dummy) = padding
-    cand_ids: jax.Array,  # (R, P) int32 new candidates per row, -1 = padding
-    cand_d: jax.Array,    # (R, P) float32
-    k: int,
-    *,
-    use_pallas: bool = True,
-    interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Batched row repair: merge per-row candidates into the live tables.
-
-    Gathers the ``rows`` out of the table, appends ``cand_*``, reruns the
-    dedup top-k merge (the construction kernel) and scatters the results
-    back — the device form of Algorithm 4 lines 9-10 over a whole batch.
-    """
-    own_ids = vk_ids[rows]
-    own_d = vk_d[rows]
-    cat_ids = jnp.concatenate([own_ids, cand_ids], axis=1)
-    cat_d = jnp.concatenate([own_d, cand_d.astype(vk_d.dtype)], axis=1)
-    cat_d = jnp.where(cat_ids < 0, jnp.inf, cat_d)
-    m_ids, m_d = topk_merge(cat_ids, cat_d, k, use_pallas=use_pallas, interpret=interpret)
-    return vk_ids.at[rows].set(m_ids), vk_d.at[rows].set(m_d)
-
-
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret", "sorted_rows"))
 def rows_purge_merge(
     vk_ids: jax.Array,    # (n+1, k) int32 live table
@@ -442,26 +413,6 @@ def shard_rows_purge_merge(
 # outside a mesh.
 # ----------------------------------------------------------------------
 
-_I32_SENTINEL = 2**31 - 1  # sorts past every valid vertex id
-
-
-def masked_unique(x: jax.Array) -> jax.Array:
-    """Sorted unique of the non-negative entries of ``x``, -1 padded.
-
-    Fixed-shape (same length as the input) device dedup: invalid entries
-    (< 0) map to an int32 sentinel that sorts last, a sort groups
-    duplicates, the first-of-run mask keeps one representative, and a
-    second sort compacts the survivors to the front. The output is the
-    ascending unique set followed by -1 pads — exactly ``np.unique`` of
-    the valid entries, which is what pins the device receiver-set
-    expansion to the host set-algebra oracle.
-    """
-    s = jnp.sort(jnp.where(x < 0, _I32_SENTINEL, x).astype(jnp.int32).ravel())
-    first = jnp.concatenate([jnp.ones(1, bool), s[1:] != s[:-1]])
-    keep = first & (s < _I32_SENTINEL)
-    compact = jnp.sort(jnp.where(keep, s, _I32_SENTINEL))
-    return jnp.where(compact == _I32_SENTINEL, -1, compact)
-
 
 def halo_candidates(
     recv_ids: jax.Array,  # (M, k) int32 received neighbor rows
@@ -517,72 +468,3 @@ def halo_fold_min(
         return jnp.minimum(cand, column(j))
 
     return jax.lax.fori_loop(1, slot.shape[1], body, column(0))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
-def rows_purge(
-    vk_ids: jax.Array,   # (n+1, k) int32 live table
-    vk_d: jax.Array,     # (n+1, k) float32
-    rows: jax.Array,     # (R,) int32 rows to purge, n (dummy) = padding
-    del_ids: jax.Array,  # (D,) int32 deleted object ids
-    k: int,
-    *,
-    use_pallas: bool = True,
-    interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Batched row purge: drop ``del_ids`` entries and recompact the rows.
-
-    Deleted entries become pad sentinels and the top-k merge re-sorts them to
-    the row tail (Algorithm 5's removal phase, vectorized over the batch).
-    """
-    own_ids = vk_ids[rows]
-    own_d = vk_d[rows]
-    hit = (own_ids[:, :, None] == del_ids[None, None, :]).any(axis=-1)
-    pid = jnp.where(hit, -1, own_ids)
-    pd = jnp.where(hit, jnp.inf, own_d)
-    m_ids, m_d = topk_merge(pid, pd, k, use_pallas=use_pallas, interpret=interpret)
-    return vk_ids.at[rows].set(m_ids), vk_d.at[rows].set(m_d)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "use_pallas", "interpret")
-)
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    block_q: int = 256,
-    block_k: int = 256,
-    use_pallas: bool = True,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Fused attention; q (B,S,H,D), kv (B,T,Hkv,D) -> (B,S,H,D)."""
-    if not use_pallas:
-        return ref.flash_attention_ref(q, k, v, causal=causal)
-    itp = (not _on_tpu()) if interpret is None else interpret
-    return flash_attention_pallas(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k, interpret=itp
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_b", "block_n", "use_pallas", "interpret"))
-def retrieval_topk(
-    scores: jax.Array,
-    k: int,
-    *,
-    block_b: int = 8,
-    block_n: int = 4096,
-    use_pallas: bool = True,
-    interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Streaming top-k (largest) over (B, N) score rows."""
-    if not use_pallas:
-        return ref.retrieval_topk_ref(scores, k)
-    b, n = scores.shape
-    bn = min(block_n, max(128, n))
-    sp = _pad_to(_pad_to(scores, 0, block_b, -jnp.inf), 1, bn, -jnp.inf)
-    itp = (not _on_tpu()) if interpret is None else interpret
-    oid, od = retrieval_topk_pallas(sp, k, block_b=block_b, block_n=bn, interpret=itp)
-    return oid[:b], od[:b]

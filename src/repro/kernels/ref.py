@@ -90,34 +90,3 @@ def minplus_matmul_ref(a: jax.Array, b: jax.Array) -> jax.Array:
     bf = b.astype(jnp.float32)
     out = jnp.min(af[:, :, None] + bf[None, :, :], axis=1)
     return out.astype(a.dtype)
-
-
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool):
-    """Dense softmax attention with GQA head repetition (fp32 math)."""
-    b, s, h, d = q.shape
-    hkv = k.shape[2]
-    if hkv != h:
-        rep = h // hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    sc = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32), k.astype(jnp.float32))
-    sc = sc / d**0.5
-    if causal:
-        mask = jnp.tril(jnp.ones((s, k.shape[1]), bool))
-        sc = jnp.where(mask, sc, -jnp.inf)
-    w = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bhst,bthd->bshd", w, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def retrieval_topk_ref(scores: jax.Array, k: int):
-    """k largest scores per row with their indices; ties -> smaller index."""
-    s = scores.astype(jnp.float32)
-
-    def row(s_r):
-        idx = jnp.arange(s_r.shape[0], dtype=jnp.int32)
-        order = jnp.lexsort((idx, -s_r))
-        return idx[order[:k]], s_r[order[:k]]
-
-    oid, od = jax.vmap(row)(s)
-    return oid, od.astype(scores.dtype)

@@ -8,7 +8,7 @@
       --out index.npz
 
 The build goes through the ``repro.knn`` facade and the ``--out`` artifact is
-``QueryEngine.save`` format, so ``serve.py --arch knn-index --artifact`` (and
+``QueryEngine.save`` format, so ``serve.py --artifact`` (and
 ``knn.load_engine``) round-trip through one file.
 """
 from __future__ import annotations

@@ -1,17 +1,11 @@
-"""Serving driver, dispatched by architecture family.
+"""Serving driver: a device-resident kNN ``QueryEngine`` loop under mixed
+traffic (batched queries + staged object updates, the paper's BUA arrival
+model):
 
-LM archs — batched prefill + autoregressive decode loop:
-
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
-      --prompt-len 32 --gen 16 --batch 4
-
-kNN archs — device-resident ``QueryEngine`` loop under mixed traffic
-(batched queries + staged object updates, the paper's BUA arrival model):
-
-  PYTHONPATH=src python -m repro.launch.serve --arch knn-index --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --smoke \
       --batch 1024 --ops 50000 --update-frac 0.05
 
-The kNN loop builds (or loads, --artifact) the index, then serves rounds of
+The loop builds (or loads, --artifact) the index, then serves rounds of
 ``query_batch`` with updates staged into the engine's queue and flushed once
 per round, printing queries/s, updates/s and the engine's serving stats as
 JSON. On the CPU container use --smoke.
@@ -22,7 +16,7 @@ trips, each serving tick stages the tick's (src, dst) moves via
 ``stage_move`` and flushes them as one fused device batch while query
 batches interleave. Reports sustained ticks/s and query p50/p99:
 
-  PYTHONPATH=src python -m repro.launch.serve --arch knn-index --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --smoke \
       --workload fleet --fleet-size 96 --ticks 50 --batch 256
 
 ``--shards N`` serves from the vertex-sharded multi-device engine
@@ -30,7 +24,7 @@ batches interleave. Reports sustained ticks/s and query p50/p99:
 across N devices. On CPU, force the device count first:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-  PYTHONPATH=src python -m repro.launch.serve --arch knn-index --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --smoke \
       --shards 8 --batch 1024 --ops 20000
 
 ``--seed`` seeds everything host-side — the network, the object draw, the
@@ -50,7 +44,7 @@ the primary path and counts ``replica_errors`` in the engine stats
 instead of failing the run:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-  PYTHONPATH=src python -m repro.launch.serve --arch knn-index --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --smoke \
       --shards 4 --hot-shard 0 --hot-frac 0.8 --replicate auto:3
 
 ``--partition SPEC`` is the unified layout surface that replaces
@@ -59,7 +53,7 @@ with --partition is an error). One spec names the whole partition layout —
 shard count, range boundaries, replication and routing policy:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-  PYTHONPATH=src python -m repro.launch.serve --arch knn-index --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --smoke \
       --partition shards=4,ranges=auto --hot-shard 0 --hot-frac 0.9
 
 ``ranges=auto`` watches the same sliding query histogram the auto-replica
@@ -84,62 +78,7 @@ import json
 import time
 from collections import deque
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.configs.registry import get_arch
-
-
-def serve_lm(args) -> np.ndarray:  # replint: disable=REP003(one-shot setup at process start; prefill/decode wrappers live for the whole serving run)
-    """Batched prefill + decode loop (GQA grouped-einsum attention, sharded
-    KV cache) — the same steps the dry-run lowers for prefill/decode cells."""
-    from repro.distributed.sharding import make_rules
-    from repro.launch.mesh import make_host_mesh
-    from repro.models import transformer as tr
-
-    arch = get_arch(args.arch)
-    cfg = arch.make_smoke() if args.smoke else arch.make_config()
-    mesh = make_host_mesh(data=len(jax.devices()))
-    rules = make_rules(mesh)
-
-    params = tr.init_params(jax.random.PRNGKey(0), cfg)
-    max_len = args.prompt_len + args.gen
-    prompts = jax.random.randint(
-        jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, cfg.vocab
-    )
-
-    prefill = jax.jit(lambda p, t: tr.prefill(p, t, cfg, max_len, rules))
-    decode = jax.jit(lambda p, c, t: tr.decode_step(p, c, t, cfg, rules),
-                     donate_argnums=(1,))
-
-    t0 = time.perf_counter()
-    logits, cache = prefill(params, prompts)
-    jax.block_until_ready(logits)
-    t_prefill = time.perf_counter() - t0
-
-    tokens = jnp.argmax(logits, -1).astype(jnp.int32)
-    generated = [tokens]
-    t0 = time.perf_counter()
-    for step in range(args.gen - 1):
-        logits, cache = decode(params, cache, tokens)
-        if args.temperature > 0:
-            key = jax.random.PRNGKey(100 + step)
-            tokens = jax.random.categorical(key, logits / args.temperature, -1).astype(jnp.int32)
-        else:
-            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
-        generated.append(tokens)
-    jax.block_until_ready(tokens)
-    t_decode = time.perf_counter() - t0
-
-    out = np.stack([np.asarray(t) for t in generated], axis=1)
-    tps = args.batch * (args.gen - 1) / max(t_decode, 1e-9)
-    print(f"model {cfg.name}: prefill({args.batch}x{args.prompt_len}) "
-          f"{t_prefill * 1e3:.1f} ms; decode {args.gen - 1} steps "
-          f"{t_decode * 1e3:.1f} ms ({tps:.1f} tok/s)")
-    print("generated token ids (first sequence):", out[0].tolist())
-    return out
-
 
 def _knn_partition_plan(args):
     """Resolve ``--partition`` vs the legacy ``--shards``/``--replicate``
@@ -206,7 +145,6 @@ def serve_knn_fleet(args, g, bn, k: int, batch: int, t_bn: float, plan=None) -> 
     wall, lat = r["wall_s"], r["lat"]
 
     stats = {
-        "arch": get_arch(args.arch).arch_id,
         "workload": "fleet",
         "n": g.n,
         "k": k,
@@ -278,12 +216,10 @@ def serve_knn(args) -> dict:
     """kNN serving loop: batched queries + staged updates on a QueryEngine."""
     from repro import knn
 
-    arch = get_arch(args.arch)
-    cfg = arch.make_smoke() if args.smoke else arch.make_config()
-    grid = args.grid or int(np.ceil(np.sqrt(cfg.n_vertices)))
-    k = args.k or cfg.k
-
-    batch = args.batch or min(cfg.query_batch, 4096)
+    # defaults: a USA-scale network (n = 2^24, k = 20, the paper's default)
+    # or, with --smoke, a 512-vertex one
+    grid, k, batch = (23, 5, 32) if args.smoke else (4096, 20, 4096)
+    grid, k, batch = args.grid or grid, args.k or k, args.batch or batch
 
     g = knn.road_network(grid, grid, seed=args.seed)
     objects = knn.pick_objects(g.n, args.mu, seed=args.seed)
@@ -435,7 +371,6 @@ def serve_knn(args) -> dict:
 
     wall = t_query + t_update
     stats = {
-        "arch": arch.arch_id,
         "n": g.n,
         "k": k,
         "batch": batch,
@@ -467,18 +402,12 @@ def serve_knn(args) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=None,
-                    help="lm: sequence batch (default 4); knn: query batch "
-                         "(default min(config query_batch, 4096))")
-    # --query-batch is an alias for --batch kept for the knn family
+    ap.add_argument("--smoke", action="store_true",
+                    help="a 512-vertex network (grid 23, k 5, batch 32) "
+                         "instead of grid 4096, k 20, batch 4096")
+    ap.add_argument("--batch", type=int, default=None, help="query batch")
+    # --query-batch is an alias for --batch
     ap.add_argument("--query-batch", type=int, default=None, dest="batch")
-    # lm options
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    # knn options
     ap.add_argument("--grid", type=int, default=None, help="grid side; n = grid^2")
     ap.add_argument("--k", type=int, default=None)
     ap.add_argument("--mu", type=float, default=0.02)
@@ -489,23 +418,23 @@ def main():
     ap.add_argument("--ops", type=int, default=50_000)
     ap.add_argument("--update-frac", type=float, default=0.05)
     ap.add_argument("--workload", choices=("random", "fleet"), default="random",
-                    help="knn update traffic: random insert/delete churn or the "
+                    help="update traffic: random insert/delete churn or the "
                          "moving-fleet stage_move workload")
     ap.add_argument("--fleet-size", type=int, default=96)
     ap.add_argument("--ticks", type=int, default=50,
                     help="fleet workload: serving ticks (one flush per tick)")
     ap.add_argument("--artifact", default=None, help="serve a knn_build --out npz")
     ap.add_argument("--fail-fast", action="store_true",
-                    help="knn: die on the first failed flush instead of "
+                    help="die on the first failed flush instead of "
                          "logging it (errors/last_error in the JSON stats) "
                          "and continuing on the last good epoch")
     ap.add_argument("--inject-flush-failure", type=int, default=0,
                     metavar="ROUND",
-                    help="knn: make the flush of round ROUND fail just "
+                    help="make the flush of round ROUND fail just "
                          "before its epoch swap (fault-injection smoke for "
                          "the graceful-degradation path)")
     ap.add_argument("--partition", default=None, metavar="SPEC",
-                    help="knn: the whole partition layout as one spec, e.g. "
+                    help="the whole partition layout as one spec, e.g. "
                          "'shards=4,replicate=auto:2,ranges=auto' (keys: "
                          "shards, ranges [equal | auto | 0:B1:B2...], "
                          "replicate [SHARD:R | auto:R], policy). ranges=auto "
@@ -518,36 +447,36 @@ def main():
                          "visible devices, e.g. "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     ap.add_argument("--replicate", default=None, metavar="SHARD:R",
-                    help="[deprecated: use --partition replicate=...] knn "
+                    help="[deprecated: use --partition replicate=...] "
                          "sharded: replicate shard SHARD onto R extra "
                          "devices and fan its queries across the replica "
                          "set; 'auto:R' picks the hottest shard from a "
                          "sliding query histogram after a short warmup")
     ap.add_argument("--hot-shard", type=int, default=0,
-                    help="knn sharded: which shard --hot-frac concentrates "
+                    help="sharded: which shard --hot-frac concentrates "
                          "queries into (default 0)")
     ap.add_argument("--hot-frac", type=float, default=0.0,
-                    help="knn sharded: fraction of each query batch drawn "
+                    help="sharded: fraction of each query batch drawn "
                          "from the hot shard's vertex range (skewed-city "
                          "traffic; 0 = uniform)")
     ap.add_argument("--hot-flip-round", type=int, default=0, metavar="ROUND",
-                    help="knn sharded: at round ROUND re-aim --hot-frac "
+                    help="sharded: at round ROUND re-aim --hot-frac "
                          "traffic at another shard's range (the zipf city "
                          "moving mid-run; exercises the ranges=auto drift "
                          "detector's second re-split)")
     ap.add_argument("--hot-shard2", type=int, default=None,
-                    help="knn sharded: the shard --hot-flip-round re-aims "
+                    help="sharded: the shard --hot-flip-round re-aims "
                          "traffic at (default: the shard opposite "
                          "--hot-shard)")
     ap.add_argument("--rebalance-ratio", type=float, default=1.25,
-                    help="knn ranges=auto: re-split when the sliding "
+                    help="ranges=auto: re-split when the sliding "
                          "window's balance ratio (hottest shard share x S, "
                          "1.0 = balanced) exceeds this")
     ap.add_argument("--rebalance-window", type=int, default=16,
-                    help="knn ranges=auto: rounds of per-vertex query "
+                    help="ranges=auto: rounds of per-vertex query "
                          "history the drift detector slides over")
     ap.add_argument("--rebalance-cooldown", type=int, default=4,
-                    help="knn ranges=auto: minimum rounds between re-splits")
+                    help="ranges=auto: minimum rounds between re-splits")
     ap.add_argument("--use-pallas", action="store_true")
     args = ap.parse_args()
 
@@ -556,17 +485,7 @@ def main():
     # must run before anything compiles: the cache dir only helps programs
     # compiled after it is configured
     sanitize.enable_compile_cache()
-
-    arch = get_arch(args.arch)
-    if arch.family == "lm":
-        args.batch = 4 if args.batch is None else args.batch
-        return serve_lm(args)
-    if arch.family == "knn":
-        return serve_knn(args)
-    raise SystemExit(
-        f"serve.py drives 'lm' and 'knn' arch families; {args.arch!r} is "
-        f"{arch.family!r} (use the train/dryrun drivers for it)"
-    )
+    return serve_knn(args)
 
 
 if __name__ == "__main__":

@@ -22,7 +22,7 @@ Build -> simulate -> query while moving::
         ids, dists = engine.query_batch(qs)   # queries see the flushed state
         engine.flush_updates()                # one fused move batch
 
-``repro.launch.serve --arch knn-index --workload fleet`` runs this loop as a
+``repro.launch.serve --workload fleet`` runs this loop as a
 service and ``benchmarks.paper_experiments.exp12_moving_fleet`` measures it.
 """
 from repro.workloads.fleet import FleetSim, drive_fleet_ticks

@@ -1,6 +1,7 @@
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single CPU device. Multi-device behaviour is tested via subprocesses
-# (tests/distributed/) that set --xla_force_host_platform_device_count.
+# (the devices_subprocess fixture) that set
+# --xla_force_host_platform_device_count.
 import os
 import subprocess
 import sys

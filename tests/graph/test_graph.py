@@ -1,11 +1,10 @@
-"""Graph substrate: CSR invariants, generators, k-hop sampler."""
+"""Graph substrate: CSR invariants and generators."""
 import numpy as np
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.graph.csr import from_edges, is_connected
 from repro.graph.generators import pick_objects, random_connected_graph, road_network
-from repro.graph.sampler import pad_subgraph, sample_khop
 
 
 def test_from_edges_symmetry_and_min_parallel():
@@ -30,19 +29,6 @@ def test_road_network_stats():
     assert g.n == 400 and is_connected(g)
     deg = g.degrees()
     assert deg.mean() < 5  # road-like sparsity
-
-
-def test_sampler_fanout_bounds():
-    g = road_network(15, 15, seed=1)
-    seeds = np.asarray([0, 7, 30], dtype=np.int64)
-    sub = sample_khop(g, seeds, (4, 3), seed=0)
-    # every seed present, edges reference valid local ids
-    assert len(sub.seeds_local) == 3
-    assert sub.edge_index.max() < len(sub.nodes)
-    # fanout bound: layer1 <= 3*4 edges, layer2 <= (3*4)*3
-    assert sub.edge_index.shape[1] <= 3 * 4 + 3 * 4 * 3
-    padded = pad_subgraph(sub, 256, 512)
-    assert padded.edge_index.shape == (2, 512) and len(padded.nodes) == 256
 
 
 def test_pick_objects_density():

@@ -135,55 +135,3 @@ def test_frontier_relax_all_pad_row_stays_inf():
             use_pallas=use_pallas,
         ))
         assert np.isinf(out).all()
-
-
-@pytest.mark.parametrize("b,n,k", [(1, 1024, 5), (8, 10000, 16), (3, 4096, 100)])
-@pytest.mark.parametrize("dtype", [np.float32])
-def test_retrieval_topk_sweep(b, n, k, dtype):
-    rng = _rng(b * n)
-    s = rng.standard_normal((b, n)).astype(dtype)
-    got_i, got_d = ops.retrieval_topk(jnp.asarray(s), k, block_b=1, block_n=1024)
-    want_i, want_d = ref.retrieval_topk_ref(jnp.asarray(s), k)
-    np.testing.assert_allclose(np.asarray(got_d), np.asarray(want_d), rtol=1e-6)
-    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
-
-
-@pytest.mark.parametrize(
-    "b,s,t,h,hkv,d,bq,bk,causal",
-    [
-        (2, 32, 32, 4, 2, 8, 8, 16, True),
-        (1, 64, 64, 4, 4, 16, 16, 16, False),
-        (2, 16, 16, 8, 2, 8, 16, 8, True),
-        (1, 48, 48, 2, 1, 32, 16, 24, True),
-    ],
-)
-def test_flash_attention_sweep(b, s, t, h, hkv, d, bq, bk, causal):
-    rng = _rng(s * t)
-    q = jnp.asarray(rng.standard_normal((b, s, h, d)), np.float32)
-    k = jnp.asarray(rng.standard_normal((b, t, hkv, d)), np.float32)
-    v = jnp.asarray(rng.standard_normal((b, t, hkv, d)), np.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
-
-
-def test_flash_attention_bf16():
-    rng = _rng(3)
-    q = jnp.asarray(rng.standard_normal((1, 32, 4, 16)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((1, 32, 2, 16)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((1, 32, 2, 16)), jnp.bfloat16)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=3e-2, atol=3e-2
-    )
-
-
-def test_retrieval_topk_matches_lax_topk():
-    rng = _rng(9)
-    s = rng.standard_normal((4, 2048)).astype(np.float32)
-    import jax
-
-    want, _ = jax.lax.top_k(jnp.asarray(s), 7)
-    _, got_d = ops.retrieval_topk(jnp.asarray(s), 7, block_b=4, block_n=512)
-    np.testing.assert_allclose(np.asarray(got_d), np.asarray(want), rtol=1e-6)

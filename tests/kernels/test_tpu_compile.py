@@ -1,25 +1,35 @@
-"""The kNN Pallas kernels compile for a TPU v5e chip.
+"""The kNN Pallas kernels and the engine's own programs compile for a TPU
+v5e chip.
 
 Interpret mode runs a kernel body on the CPU but does not apply the TPU
 compiler's rules (block-shape tiling, lowerable primitives, fast-memory
 limits). These tests compile ``sweep_merge``, ``frontier_relax`` and
 ``topk_merge`` through their ``ops`` wrappers, with ``interpret=False``, for
 one chip of a described ``v5e:2x2`` topology at n = 2^20, k = 20, chunk 512
-and B = 512, and the whole construction sweep program at the same n and k —
-nothing runs, so no chip is needed. The topology is described
-only inside the module fixture below, never at import.
+and B = 512, and the whole construction sweep program at the same n and k.
+They also compile every flush program a ``QueryEngine`` lists
+(``_flush_signatures``) for a 40 x 40 road network, and the query program
+(``ops.answer_program(ops.serve_gather)``) at the benchmark cells' shapes —
+nothing runs, so no chip is needed. The topology is described only inside
+the module fixture below, never at import.
 """
 from __future__ import annotations
 
+import functools
 import os
 import re
+from concurrent.futures import ThreadPoolExecutor
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from repro import knn
 from repro.core import construct_jax
+from repro.core.reference import knn_index_cons_plus
+from repro.graph.generators import road_network
 from repro.kernels import ops
 
 N, K, CHUNK, B = 1 << 20, 20, 512, 512
@@ -121,3 +131,75 @@ def test_sweep_program_loop_copies_no_table_on_tpu(one_chip):
             inner.append(line.strip())
     assert " while(" in hlo
     assert not inner
+
+
+@functools.cache
+def _fleet_engine():
+    """The QueryEngine of tests/core/test_flush_programs.py: a 40 x 40 road
+    network, K = 6, an object at every 20th vertex."""
+    g = road_network(40, 40, seed=1)
+    bn = knn.build_bngraph(g)
+    objects = np.arange(0, g.n, 20, dtype=np.int32)
+    return knn.QueryEngine.from_index(knn_index_cons_plus(bn, objects, 6), objects, bn=bn)
+
+
+def _on_chip(arg, one_chip):
+    if isinstance(arg, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(arg.shape, arg.dtype, sharding=one_chip)
+    return arg
+
+
+@pytest.fixture(scope="module")
+def flush_compiles(one_chip):
+    """Every flush program compiled for the chip as ``_warm_flush`` compiles
+    them (lowered one after another, compiled in parallel): signature ->
+    None, or the error its lowering or compile raised."""
+    eng = _fleet_engine()
+
+    def lower(sig):
+        fn, args, kwargs = eng._flush_program(sig)
+        try:
+            return fn.lower(*(_on_chip(a, one_chip) for a in args), **kwargs)
+        except Exception as e:  # noqa: BLE001 — reported by the signature's case
+            return e
+
+    def compile_(low):
+        if isinstance(low, Exception):
+            return low
+        try:
+            low.compile()
+        except Exception as e:  # noqa: BLE001 — reported by the signature's case
+            return e
+        return None
+
+    sigs = eng._flush_signatures()
+    lowered = [lower(sig) for sig in sigs]
+    with ThreadPoolExecutor(os.cpu_count()) as pool:
+        return dict(zip(sigs, pool.map(compile_, lowered)))
+
+
+@pytest.mark.parametrize("sig", _fleet_engine()._flush_signatures(),
+                         ids=lambda sig: "-".join(map(str, sig)))
+def test_every_flush_signature_compiles_for_tpu(flush_compiles, sig):
+    """Each program a flush dispatches, on the arguments it is dispatched
+    with (``QueryEngine._flush_program``), compiles for the chip."""
+    if flush_compiles[sig] is not None:
+        raise flush_compiles[sig]
+
+
+@pytest.mark.parametrize("b,k", [(4096, 20), (1024, 10)])
+def test_query_program_compiles_for_tpu(one_chip, b, k):
+    """The query batch's one program at the cells' shapes (n = 131,044 plus
+    the dummy row): its single output is the packed (B*2k,) int32 answer."""
+    rows = 131_044 + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = ops.answer_program(ops.serve_gather).lower(
+        sds((rows, k), jnp.int32), sds((rows, k), jnp.float32),
+        sds((b,), jnp.int32), sds((b,), jnp.int32),
+    ).compile()
+    out = compiled.out_info
+    assert isinstance(out, jax.ShapeDtypeStruct)
+    assert (out.shape, out.dtype) == ((b * 2 * k,), jnp.int32)
