@@ -18,10 +18,13 @@ This module runs the whole sweep as a *fused, device-resident schedule*:
   The entire schedule is uploaded **once** per sweep (explicit
   ``jax.device_put``); nothing else crosses the host/device boundary until
   the final result readback.
-  Ragged-aware bucketing (power-of-4 neighbor widths, capped at the global
-  max, two chunk tiers) caps padding waste; the plan reports ``occupancy``
-  for the flat layout next to ``occupancy_levelwise`` for the seed's
-  per-level power-of-two padding.
+  Ragged-aware bucketing caps padding waste: each level is sorted by
+  degree, widest first, and cut into chunks (two chunk tiers, by level
+  size); each chunk's neighbor width T is the power-of-4 bucket (capped at
+  the global max) of its own widest row, not of its level's. Reordering a
+  level changes no result, since its vertices are mutually independent. The
+  plan reports its padded neighbor ``cells`` and ``occupancy`` next to
+  ``occupancy_levelwise`` for the seed's per-level power-of-two padding.
 
 * ``run_sweep`` executes one direction as a **single jitted program**: a
   ``lax.fori_loop`` over chunks whose body ``lax.switch``es into one branch
@@ -50,6 +53,7 @@ order the paper's total rank refines.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +103,7 @@ class SweepPlan:
     chunk_off: jax.Array      # (Nc,) int32: first row of each chunk in its bucket
     num_chunks: int
     level_sizes: tuple[int, ...]
+    cells: int                  # padded neighbor cells of the flat schedule
     occupancy: float            # true neighbor cells / flat padded cells
     occupancy_levelwise: float  # same metric under per-level pow2 padding (seed)
 
@@ -116,7 +121,15 @@ def _next_pow2(x: int, lo: int = 8) -> int:
 
 
 def prepare_sweep(bn: BNGraph, direction: str) -> SweepPlan:
-    """Extract one direction's schedule and upload it to the device, once."""
+    """Extract one direction's schedule and upload it to the device, once.
+
+    Each level is sorted by degree, widest first (a stable sort), and cut
+    into chunks; each chunk takes the width bucket of its own first, widest
+    row, so a level's few wide vertices do not pad all its narrow ones. A
+    level may be reordered and split across buckets because its vertices
+    are mutually independent (``BNGraph.level_members``): no row's inputs
+    change, and chunks still run in level order.
+    """
     level_of, ids_tab, w_tab = bn.sweep_tables(direction)
     n = bn.n
     deg = (ids_tab >= 0).sum(axis=1)
@@ -132,30 +145,37 @@ def prepare_sweep(bn: BNGraph, direction: str) -> SweepPlan:
     levelwise_cells = 0
     for vs in levels:
         t_true = int(deg[vs].max())
-        t_pad = _t_bucket(t_true, cap)
         chunk = CHUNK_LARGE if len(vs) >= _LARGE_LEVEL else CHUNK_SMALL
-        rows = -(-len(vs) // chunk) * chunk
-        key = (t_pad, chunk)
-        b = acc.setdefault(key, {"verts": [], "nbr": [], "w": [], "rows": 0})
-        verts = np.full(rows, n, np.int32)
-        verts[: len(vs)] = vs
-        nbr = np.full((rows, t_pad), -1, np.int32)
-        w = np.full((rows, t_pad), _INF, np.float32)
-        t_copy = min(t_pad, ids_tab.shape[1])
-        nbr[: len(vs), :t_copy] = ids_tab[vs][:, :t_copy]
-        w[: len(vs), :t_copy] = w_tab[vs][:, :t_copy].astype(np.float32)
-        w[nbr < 0] = _INF
-        start = b["rows"]
-        b["verts"].append(verts)
-        b["nbr"].append(nbr)
-        b["w"].append(w)
-        b["rows"] += rows
-        bid = key_index.setdefault(key, len(key_index))
-        for c in range(rows // chunk):
-            chunk_bucket.append(bid)
-            chunk_off.append(start + c * chunk)
+        # widest first, so each chunk's first row sets its width; chunks of
+        # one width are adjacent and go to their bucket as one run
+        vs = vs[np.argsort(-deg[vs], kind="stable")]
+        widths = [_t_bucket(int(d), cap) for d in deg[vs[::chunk]]]
+        lo = 0
+        for t_pad, run in itertools.groupby(widths):
+            hi = lo + chunk * len(list(run))
+            part, lo = vs[lo:hi], hi
+            rows = -(-len(part) // chunk) * chunk
+            key = (t_pad, chunk)
+            b = acc.setdefault(key, {"verts": [], "nbr": [], "w": [], "rows": 0})
+            verts = np.full(rows, n, np.int32)
+            verts[: len(part)] = part
+            nbr = np.full((rows, t_pad), -1, np.int32)
+            w = np.full((rows, t_pad), _INF, np.float32)
+            t_copy = min(t_pad, ids_tab.shape[1])
+            nbr[: len(part), :t_copy] = ids_tab[part][:, :t_copy]
+            w[: len(part), :t_copy] = w_tab[part][:, :t_copy].astype(np.float32)
+            w[nbr < 0] = _INF
+            start = b["rows"]
+            b["verts"].append(verts)
+            b["nbr"].append(nbr)
+            b["w"].append(w)
+            b["rows"] += rows
+            bid = key_index.setdefault(key, len(key_index))
+            for c in range(rows // chunk):
+                chunk_bucket.append(bid)
+                chunk_off.append(start + c * chunk)
+            flat_cells += rows * t_pad
         true_cells += int(deg[vs].sum())
-        flat_cells += rows * t_pad
         levelwise_cells += _next_pow2(len(vs)) * (_next_pow2(t_true, lo=1) if t_true else 1)
 
     buckets = []
@@ -178,6 +198,7 @@ def prepare_sweep(bn: BNGraph, direction: str) -> SweepPlan:
         chunk_off=jax.device_put(np.asarray(chunk_off, np.int32)),
         num_chunks=len(chunk_bucket),
         level_sizes=tuple(len(vs) for vs in levels),
+        cells=flat_cells,
         occupancy=true_cells / max(1, flat_cells),
         occupancy_levelwise=true_cells / max(1, levelwise_cells),
     )
@@ -340,10 +361,10 @@ def build_knn_tables_jax(
         plan_up, plan_down = plans or (prepare_sweep(bn, "up"), prepare_sweep(bn, "down"))
 
         # ---- bottom-up: V_k^< (Lemma 5.12) ----
-        with span("build.sweep", direction="up"):
+        with span("build.sweep", direction="up", cells=plan_up.cells):
             vkl_ids, vkl_d = run_sweep(plan_up, ex_ids, ex_d, k, use_pallas=use_pallas)
         # ---- top-down: V_k (Lemma 5.21), extras = own V_k^< rows, still on device ----
-        with span("build.sweep", direction="down"):
+        with span("build.sweep", direction="down", cells=plan_down.cells):
             vk_ids, vk_d = run_sweep(plan_down, vkl_ids, vkl_d, k, use_pallas=use_pallas)
         if mesh is None:
             return vk_ids, vk_d

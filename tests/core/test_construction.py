@@ -17,6 +17,7 @@ from repro.core.construct_jax import (
 )
 from repro.core.index import KNNIndex, indices_equivalent
 from repro.core.reference import dijkstra_cons, knn_index_cons, knn_index_cons_plus
+from repro.graph.csr import from_edges
 from repro.graph.generators import pick_objects, random_connected_graph, road_network
 
 params = st.tuples(
@@ -101,31 +102,101 @@ def test_run_sweep_zero_host_transfers():
     assert indices_equivalent(ref, KNNIndex(ids=ids, dists=dists, k=k))
 
 
+def _cul_de_sac_city():
+    """A 12 x 12 road network with dead-end streets: 8 intersections get 20
+    each, 8 get 8 and 8 get 2, so the bottom-up level those intersections
+    share mixes three neighbor-width tiers."""
+    g = road_network(12, 12, seed=1)
+    u, v, w = g.edge_list()
+    edges = list(zip(u.tolist(), v.tolist(), w.tolist()))
+    n = g.n
+    hubs = [x * 12 + y for x in range(0, 12, 2) for y in range(0, 12, 2)][:24]
+    for i, h in enumerate(hubs):
+        for _ in range((20, 8, 2)[i // 8]):
+            edges.append((h, n, float(1 + n % 9)))
+            n += 1
+    return from_edges(n, edges)
+
+
+def _chunks(plan):
+    """(bucket, first row in it, the row ids) of each chunk, in sweep order."""
+    for b, off in zip(np.asarray(plan.chunk_bucket).tolist(),
+                      np.asarray(plan.chunk_off).tolist()):
+        bucket = plan.buckets[b]
+        yield bucket, off, np.asarray(bucket.verts)[off : off + bucket.chunk]
+
+
+def _level_tiers(bn, plan):
+    """Distinct chunk widths T of each level."""
+    level_of = bn.sweep_tables(plan.direction)[0]
+    tiers = {}
+    for bucket, _, verts in _chunks(plan):
+        tiers.setdefault(int(level_of[verts[0]]), set()).add(bucket.t_pad)
+    return tiers
+
+
+@pytest.mark.parametrize("direction", ["up", "down"])
+def test_sweep_plan_sizes_each_chunk_to_its_rows(direction):
+    """Each chunk holds rows of one level, in level order, at a width T that
+    fits every row's neighbors; the plan pads fewer cells than giving every
+    chunk its level's widest row would."""
+    bn = build_bngraph(_cul_de_sac_city())
+    level_of, ids_tab, w_tab = bn.sweep_tables(direction)
+    deg = (ids_tab >= 0).sum(axis=1)
+    plan = prepare_sweep(bn, direction)
+    levels = []
+    for bucket, off, verts in _chunks(plan):
+        t = bucket.t_pad
+        real = verts[verts < bn.n]
+        assert real.size and (verts[real.size:] == bn.n).all()
+        assert len(set(level_of[real].tolist())) == 1
+        levels.append(int(level_of[real[0]]))
+        nbr = np.asarray(bucket.nbr)[off * t : (off + bucket.chunk) * t].reshape(-1, t)
+        w = np.asarray(bucket.w)[off * t : (off + bucket.chunk) * t].reshape(-1, t)
+        for row, v in enumerate(real.tolist()):
+            assert deg[v] <= t
+            np.testing.assert_array_equal(nbr[row, : deg[v]], ids_tab[v, : deg[v]])
+            np.testing.assert_array_equal(w[row, : deg[v]], w_tab[v, : deg[v]])
+            assert (nbr[row, deg[v]:] == -1).all() and np.isinf(w[row, deg[v]:]).all()
+    assert levels == sorted(levels)
+    assert plan.cells == sum(b.nbr.shape[0] for b in plan.buckets)
+    cap = construct_jax._next_pow2(int(deg.max()), lo=4)
+    levelwise = 0
+    for vs in bn.level_members(direction):
+        chunk = (construct_jax.CHUNK_LARGE if len(vs) >= construct_jax._LARGE_LEVEL
+                 else construct_jax.CHUNK_SMALL)
+        levelwise += (-(-len(vs) // chunk) * chunk
+                      * construct_jax._t_bucket(int(deg[vs].max()), cap))
+    assert plan.cells < levelwise
+
+
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_sweep_padded_rows_write_nothing(use_pallas):
     """Rows that pad a chunk, or pad a branch's rows to the widest CHUNK,
     leave the dummy row n at (-1, +inf) and every real row as the reference
-    has it, on a plan whose levels do not fill their chunks in either tier."""
-    g = road_network(12, 12, seed=1)
-    objects = pick_objects(g.n, 0.2, seed=1)
-    bn = build_bngraph(g)
+    has it, on plans whose levels do not fill their chunks in either tier,
+    one of them a level whose chunks take three widths."""
     k = 6
-    plans = prepare_sweep(bn, "up"), prepare_sweep(bn, "down")
-    up = plans[0]
-    assert {c for _, c in up.bucket_signature()} == {
-        construct_jax.CHUNK_SMALL, construct_jax.CHUNK_LARGE}
-    assert any(s >= construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_LARGE
-               for s in up.level_sizes)
-    assert any(s < construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_SMALL
-               for s in up.level_sizes)
-    tables = object_extras(g.n, objects, k)
-    for plan in plans:
-        tables = run_sweep(plan, *tables, k, use_pallas=use_pallas)
-        ids, d = (np.asarray(x) for x in tables)
-        assert (ids[g.n] == -1).all() and np.isinf(d[g.n]).all()
-    dists = np.where(ids[: g.n] >= 0, d[: g.n].astype(np.float64), np.inf)
-    ref = knn_index_cons_plus(bn, objects, k)
-    assert indices_equivalent(ref, KNNIndex(ids=ids[: g.n], dists=dists, k=k))
+    for g in (road_network(12, 12, seed=1), _cul_de_sac_city()):
+        objects = pick_objects(g.n, 0.2, seed=1)
+        bn = build_bngraph(g)
+        plans = prepare_sweep(bn, "up"), prepare_sweep(bn, "down")
+        up = plans[0]
+        assert {c for _, c in up.bucket_signature()} == {
+            construct_jax.CHUNK_SMALL, construct_jax.CHUNK_LARGE}
+        assert any(s >= construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_LARGE
+                   for s in up.level_sizes)
+        assert any(s < construct_jax._LARGE_LEVEL and s % construct_jax.CHUNK_SMALL
+                   for s in up.level_sizes)
+        tables = object_extras(g.n, objects, k)
+        for plan in plans:
+            tables = run_sweep(plan, *tables, k, use_pallas=use_pallas)
+            ids, d = (np.asarray(x) for x in tables)
+            assert (ids[g.n] == -1).all() and np.isinf(d[g.n]).all()
+        dists = np.where(ids[: g.n] >= 0, d[: g.n].astype(np.float64), np.inf)
+        ref = knn_index_cons_plus(bn, objects, k)
+        assert indices_equivalent(ref, KNNIndex(ids=ids[: g.n], dists=dists, k=k))
+    assert max(len(t) for t in _level_tiers(bn, up).values()) >= 3
 
 
 @pytest.mark.parametrize("direction", ["up", "down"])

@@ -268,6 +268,8 @@ def test_spans_land_in_a_profiler_trace(tmp_path, sanitize_off):
     (bs, be, _), = by["knn:build"]
     sweeps = by["knn:build.sweep"]
     assert [a["direction"] for _, _, a in sweeps] == ["up", "down"]
+    assert [a["cells"] for _, _, a in sweeps] == [
+        construct_jax.prepare_sweep(bn, d).cells for d in ("up", "down")]
     assert all(bs <= s and e <= be for s, e, _ in sweeps + by["knn:build.extras"])
 
 
